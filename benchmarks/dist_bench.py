@@ -83,12 +83,13 @@ def bench(steps: int, policies: list[str], paths: list[str]) -> dict:
 
     from repro.dist import AUTO, accounting
     from repro.launch.hlo_analysis import analyze_hlo
+    from repro.launch.mesh import make_mesh
     from repro.train.loop import (init_dp_state, init_fsdp_state,
                                   make_dp_train_step, make_fsdp_train_step)
 
     api, params, batcher = _build()
     n = jax.device_count()
-    mesh = jax.make_mesh((n,), ("data",))
+    mesh = make_mesh((n,), ("data",))
     rows = []
     for path in paths:
         for name in policies:
@@ -110,7 +111,7 @@ def bench(steps: int, policies: list[str], paths: list[str]) -> dict:
             # one wrapper per lane: lower/compile and the timed run share
             # the same jit cache, so _measure never recompiles the step
             jitted = jax.jit(step)  # repro: noqa[JIT-001] step is a fresh closure per (path, policy) lane — one wrapper per lane is the minimum
-            with mesh:
+            with jax.set_mesh(mesh):
                 compiled = jitted.lower(state, batcher(0)).compile()
                 compile_s = time.monotonic() - t0
                 cost = analyze_hlo(compiled.as_text(), total_devices=n)

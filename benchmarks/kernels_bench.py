@@ -51,7 +51,7 @@ def rows():
     us = _time(ref_bag, idx2)
     out.append(("kernel/qr_bag/ref_jnp", round(us, 1),
                 f"hbm_bytes_unfused={b * l * d * 4 * 3 + b * d * 4}"))
-    us = _time(lambda i: ops.qr_bag_lookup(i, mask, wr, wq), idx2)
+    us = _time(lambda i: ops.serve_bag_pool(i, mask, wr, wq), idx2)
     out.append(("kernel/qr_bag/pallas_interpret", round(us, 1),
                 f"hbm_bytes_fused={b * l * d * 4 * 2 + b * d * 4}"))
 

@@ -29,7 +29,7 @@ class KernelProgram:
 def _bf16_qr_bag_kernel():
     import jax.numpy as jnp
     import numpy as np
-    from ..kernels.embedding_bag import qr_embedding_bag
+    from ..kernels.serve_path import fused_serve_pool
     rng = np.random.default_rng(0)
     b, l, m, q, d = 4, 8, 16, 8, 32
     rem = jnp.asarray(rng.integers(0, m, (b, l)), jnp.int32)
@@ -39,44 +39,43 @@ def _bf16_qr_bag_kernel():
     w_quo = jnp.asarray(rng.normal(size=(q, d)), jnp.bfloat16)
 
     def fn(rem, quo, mask, w_rem, w_quo):
-        return qr_embedding_bag(rem, quo, mask, w_rem, w_quo, op="mult",
-                                interpret=True)
+        return fused_serve_pool(rem, mask, w_rem, idx_b=quo, w_b=w_quo,
+                                op="mult")
     return fn, (rem, quo, mask, w_rem, w_quo)
 
 
 def _bf16_qr_gather_kernel():
     import jax.numpy as jnp
     import numpy as np
-    from ..kernels.qr_gather import qr_gather
+    from ..kernels.ops import qr_lookup
     rng = np.random.default_rng(1)
     n, m, q, d = 32, 16, 8, 32
-    rem = jnp.asarray(rng.integers(0, m, (n,)), jnp.int32)
-    quo = jnp.asarray(rng.integers(0, q, (n,)), jnp.int32)
+    idx = jnp.asarray(rng.integers(0, m * q, (n,)), jnp.int32)
     w_rem = jnp.asarray(rng.normal(size=(m, d)), jnp.bfloat16)
     w_quo = jnp.asarray(rng.normal(size=(q, d)), jnp.bfloat16)
 
-    def fn(rem, quo, w_rem, w_quo):
-        return qr_gather(rem, quo, w_rem, w_quo, op="add", interpret=True)
-    return fn, (rem, quo, w_rem, w_quo)
+    def fn(idx, w_rem, w_quo):
+        return qr_lookup(idx, w_rem, w_quo, op="add")
+    return fn, (idx, w_rem, w_quo)
 
 
 def _int8_qr_gather_kernel():
     import jax.numpy as jnp
     import numpy as np
-    from ..kernels.qr_gather import qr_gather_quant
+    from ..kernels.ops import qr_lookup
     rng = np.random.default_rng(2)
     n, m, q, d = 32, 16, 8, 32
-    rem = jnp.asarray(rng.integers(0, m, (n,)), jnp.int32)
-    quo = jnp.asarray(rng.integers(0, q, (n,)), jnp.int32)
-    w_rem = jnp.asarray(rng.integers(-127, 128, (m, d)), jnp.int8)
-    w_quo = jnp.asarray(rng.integers(-127, 128, (q, d)), jnp.int8)
-    rm = jnp.asarray(rng.uniform(0.01, 0.1, (m, 2)), jnp.float32)
-    qm = jnp.asarray(rng.uniform(0.01, 0.1, (q, 2)), jnp.float32)
 
-    def fn(rem, quo, w_rem, w_quo, rm, qm):
-        return qr_gather_quant(rem, quo, w_rem, w_quo, rm, qm,
-                               op="mult", interpret=True)
-    return fn, (rem, quo, w_rem, w_quo, rm, qm)
+    def table(rows):
+        return {"q": jnp.asarray(rng.integers(-127, 128, (rows, d)), jnp.int8),
+                "scale": jnp.asarray(rng.uniform(0.01, 0.1, (rows, 1)),
+                                     jnp.bfloat16),
+                "zp": jnp.asarray(rng.integers(-8, 8, (rows, 1)), jnp.int8)}
+    idx = jnp.asarray(rng.integers(0, m * q, (n,)), jnp.int32)
+
+    def fn(idx, w_rem, w_quo):
+        return qr_lookup(idx, w_rem, w_quo, op="mult")
+    return fn, (idx, table(m), table(q))
 
 
 def _bf16_fused_serve_kernel():
@@ -91,7 +90,7 @@ def _bf16_fused_serve_kernel():
     proj = jnp.asarray(rng.normal(size=(d, d_out)), jnp.bfloat16)
 
     def fn(idx, mask, w, proj):
-        return fused_serve_pool(idx, mask, w, proj=proj, interpret=True)
+        return fused_serve_pool(idx, mask, w, proj=proj)
     return fn, (idx, mask, w, proj)
 
 
@@ -110,15 +109,14 @@ def _int8_fused_serve_kernel():
 
     def fn(idx_a, mask, w_a, idx_b, w_b, meta):
         return fused_serve_pool(idx_a, mask, w_a, idx_b=idx_b, w_b=w_b,
-                                meta_a=meta, meta_b=meta, op="mult",
-                                interpret=True)
+                                meta_a=meta, meta_b=meta, op="mult")
     return fn, (idx_a, mask, w_a, idx_b, w_b, meta)
 
 
 def _bf16_qr_bag_jnp():
     import jax.numpy as jnp
     import numpy as np
-    from ..kernels.ops import qr_bag_lookup
+    from ..kernels.ops import serve_bag_pool
     rng = np.random.default_rng(5)
     b, l, m, q, d = 4, 8, 16, 8, 32
     idx = jnp.asarray(rng.integers(0, m * q, (b, l)), jnp.int32)
@@ -127,8 +125,8 @@ def _bf16_qr_bag_jnp():
     w_quo = jnp.asarray(rng.normal(size=(q, d)), jnp.bfloat16)
 
     def fn(idx, mask, w_rem, w_quo):
-        return qr_bag_lookup(idx, mask, w_rem, w_quo, op="concat",
-                             use_kernel=False)
+        return serve_bag_pool(idx, mask, w_rem, w_quo, op="concat",
+                              use_kernel=False)
     return fn, (idx, mask, w_rem, w_quo)
 
 
@@ -159,7 +157,7 @@ def _bf16_dot_interaction():
     x = jnp.asarray(rng.normal(size=(b, f, d)), jnp.bfloat16)
 
     def fn(x):
-        return dot_interaction(x, interpret=True)
+        return dot_interaction(x)
     return fn, (x,)
 
 
@@ -167,21 +165,21 @@ def kernel_programs() -> list[KernelProgram]:
     """Every serve/train-kernel-reachable program the f32-accumulation
     audit certifies, with worst-case bf16/int8 operands."""
     return [
-        KernelProgram("embedding_bag.qr_embedding_bag[bf16]",
+        KernelProgram("serve_path.fused_serve_pool[bf16 qr bag]",
                       _bf16_qr_bag_kernel,
                       "fused QR bag kernel, bf16 tables"),
-        KernelProgram("qr_gather.qr_gather[bf16]", _bf16_qr_gather_kernel,
-                      "fused QR gather kernel, bf16 tables"),
-        KernelProgram("qr_gather.qr_gather_quant[int8]",
-                      _int8_qr_gather_kernel,
-                      "fused int8-dequant QR gather kernel"),
+        KernelProgram("ops.qr_lookup[bf16]", _bf16_qr_gather_kernel,
+                      "QR gather as one-slot bags of the fused kernel, "
+                      "bf16 tables"),
+        KernelProgram("ops.qr_lookup[int8]", _int8_qr_gather_kernel,
+                      "int8-dequant QR gather through the fused kernel"),
         KernelProgram("serve_path.fused_serve_pool[bf16+proj]",
                       _bf16_fused_serve_kernel,
                       "fused serve kernel, bf16 table + projection"),
         KernelProgram("serve_path.fused_serve_pool[int8 qr]",
                       _int8_fused_serve_kernel,
                       "fused serve kernel, quantized QR pair"),
-        KernelProgram("ops.qr_bag_lookup[bf16 jnp]", _bf16_qr_bag_jnp,
+        KernelProgram("ops.serve_bag_pool[bf16 jnp]", _bf16_qr_bag_jnp,
                       "jnp fallback bag path (concat op), bf16 tables"),
         KernelProgram("compositional.bag_pool[bf16 qr]", _bf16_bag_pool,
                       "model-side pooled lookup, bf16 QR module"),

@@ -37,7 +37,8 @@ def _mesh_and_n():
     n = jax.device_count()
     if n < 2:
         return None, n
-    return jax.make_mesh((n,), ("data",)), n
+    from ..launch.mesh import make_mesh
+    return make_mesh((n,), ("data",)), n
 
 
 def _dp_psum(mode: str):
@@ -46,7 +47,7 @@ def _dp_psum(mode: str):
     def build(mesh, n):
         import jax
         import jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from ..dist import accounting
         from ..dist.compress import ef_psum_grads, init_error_state
@@ -58,7 +59,7 @@ def _dp_psum(mode: str):
             return ef_psum_grads(g, e, axis_name="data", mode=mode)
 
         fn = jax.jit(shard_map(body, mesh=mesh, in_specs=(P(), P()),
-                               out_specs=(P(), P()), check_rep=False))
+                               out_specs=(P(), P()), check_vma=False))
         lowered = fn.lower(grads, err)
         closed = accounting.grad_wire_bytes(
             grads, mode, n, pattern="all_reduce")["total_bytes"]
@@ -73,7 +74,7 @@ def _fsdp(mode: str):
         import math
         import jax
         import jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from ..dist import accounting
         from ..dist.compress import _reduce_scatter_leaf, init_error_state
@@ -89,7 +90,7 @@ def _fsdp(mode: str):
             return outs, new_e
 
         fn = jax.jit(shard_map(body, mesh=mesh, in_specs=(P(), P()),
-                               out_specs=(P(), P()), check_rep=False))
+                               out_specs=(P(), P()), check_vma=False))
         lowered = fn.lower(leaves, err)
         closed = sum(
             accounting.leaf_reduce_bytes(mode, math.prod(v.shape), n,
@@ -107,7 +108,7 @@ def _serve_exchange(quantized: bool):
         import jax
         import jax.numpy as jnp
         import numpy as np
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from ..dist import accounting
         from ..dist.serve_placement import exchange_rows
@@ -126,7 +127,7 @@ def _serve_exchange(quantized: bool):
             return exchange_rows(leaf, ids, n, rpd, axis="data")
 
         fn = jax.jit(shard_map(body, mesh=mesh, in_specs=(spec, P("data")),
-                               out_specs=P("data"), check_rep=False))
+                               out_specs=P("data"), check_vma=False))
         lowered = fn.lower(leaf, ids)
         closed = accounting.serve_exchange_wire_bytes(
             lookups, width, n, quantized=quantized,

@@ -25,6 +25,7 @@ __all__ = [
     "CompositionalEmbedding",
     "qr_embedding",
     "bag_pool",
+    "masked_bag_sum",
     "table_rows",
     "is_quantized_table",
 ]
@@ -233,13 +234,37 @@ def bag_pool(module, params, idx, mask=None, gather=None):
 
     ``idx``: int array ``(..., L)``; ``mask``: optional ``(..., L)`` (1 keeps
     the row).  Returns ``(..., dim)``.  This is the contract the fused
-    Pallas ``embedding_bag`` kernel implements.  ``gather`` substitutes
+    Pallas kernel (``kernels.serve_path``) implements.  ``gather`` substitutes
     the row fetch (see ``_gather``) — the sharded serve path's hook.
     """
     emb = module.apply(params, idx, gather=gather)  # (..., L, D)
     # pool in f32, round once (accumulation-audit convention): a bf16
     # running sum would round every one of the L adds
-    pooled = emb.astype(jnp.float32)
+    return masked_bag_sum(emb, mask).astype(emb.dtype)
+
+
+def masked_bag_sum(rows, mask=None):
+    """``sum_l rows[..., l, :] * mask[..., l]`` in f32, in a fixed order.
+
+    Every XLA pooling path (``bag_pool``, the engine's cache and sharded
+    programs, the kernel oracles) sums through here.  A ``reduce`` leaves
+    the order of the L adds to the compiler, which picks it per fusion, so
+    two programs pooling the same rows could disagree in the last bit.
+    Halving the bag explicitly — slot ``l`` plus slot ``l + P/2`` of the
+    bag zero-padded to a power of two ``P`` — fixes one order for all of
+    them in ``log2 P`` vector adds (L unrolled adds cost 42% at L=64 on
+    v5e, see PERF.md).
+    """
+    x = rows.astype(jnp.float32)
     if mask is not None:
-        pooled = pooled * mask[..., None].astype(jnp.float32)
-    return pooled.sum(axis=-2).astype(emb.dtype)
+        x = x * mask[..., None].astype(jnp.float32)
+    l = x.shape[-2]
+    if l == 0:
+        return jnp.zeros(x.shape[:-2] + x.shape[-1:], jnp.float32)
+    p = 1 << (l - 1).bit_length()
+    if p != l:
+        x = jnp.pad(x, [(0, 0)] * (x.ndim - 2) + [(0, p - l), (0, 0)])
+    while x.shape[-2] > 1:
+        h = x.shape[-2] // 2
+        x = x[..., :h, :] + x[..., h:, :]
+    return x[..., 0, :]

@@ -23,8 +23,9 @@ mode        DP all-reduce path             FSDP reduce-scatter path
 ``int8``    2(n−1)/n · 1E′ + scales        (n−1)/n · 1E + scale
 ==========  =============================  =============================
 
-(E′ = E padded to a multiple of n — the compressed all-reduce is the
-two-phase all_to_all + all_gather exchange over the flattened leaf;
+(E′ = E with the leaf's leading dim padded to a multiple of n — the
+compressed all-reduce is the two-phase all_to_all + all_gather exchange
+of blocks of leading-dim rows;
 "scales" are the pmax-shared f32 scalar all-reduces, int8 only.)  The FSDP path additionally all-gathers every
 updated param shard: (n−1)/n · 4E per scattered leaf — reported
 separately so "gradient wire" and "param wire" stay distinguishable.
@@ -65,29 +66,29 @@ def ring_all_to_all_bytes(nbytes: float, n: int) -> float:
 
 
 def leaf_reduce_bytes(mode: str, nelems: int, n: int, *,
-                      pattern: str = "all_reduce") -> float:
+                      pattern: str = "all_reduce", row: int = 1) -> float:
     """Wire bytes per chip to reduce one gradient leaf.
 
     ``pattern``: ``"all_reduce"`` (DP step — every device ends with the
     full reduced leaf) or ``"reduce_scatter"`` (FSDP step — each device
-    ends with its shard; no phase-2 gather for int8).
+    ends with its shard; no phase-2 gather for int8).  ``row``: elements
+    per leading-dim row; the compressed all-reduce pads whole rows.
     """
     if n <= 1 or nelems == 0:
         return 0.0
+    padded = float(math.ceil(nelems / (row * n)) * n * row)
     if mode == "none":
         full = 4.0 * nelems
         return (ring_all_reduce_bytes(full, n) if pattern == "all_reduce"
                 else ring_reduce_scatter_bytes(full, n))
     if mode == "bf16":
         if pattern == "all_reduce":
-            padded = 2.0 * math.ceil(nelems / n) * n
-            return (ring_all_to_all_bytes(padded, n)
-                    + ring_all_gather_bytes(padded, n))
+            return (ring_all_to_all_bytes(2.0 * padded, n)
+                    + ring_all_gather_bytes(2.0 * padded, n))
         return ring_reduce_scatter_bytes(2.0 * nelems, n)
     if mode == "int8":
         scale = ring_all_reduce_bytes(_SCALE_BYTES, n)
         if pattern == "all_reduce":
-            padded = float(math.ceil(nelems / n) * n)
             return (ring_all_to_all_bytes(padded, n)
                     + ring_all_gather_bytes(padded, n) + 2 * scale)
         return ring_all_to_all_bytes(float(nelems), n) + scale
@@ -115,7 +116,8 @@ def grad_wire_bytes(grads_like, policy, n: int, *, pattern: str = "all_reduce",
     for path, leaf, mode, scat in zip(paths, leaves, modes, scattered):
         nelems = int(math.prod(leaf.shape)) if leaf.shape else 1
         b = leaf_reduce_bytes(mode, nelems, n,
-                              pattern="reduce_scatter" if scat else "all_reduce")
+                              pattern="reduce_scatter" if scat else "all_reduce",
+                              row=int(math.prod(leaf.shape[1:])))
         per_leaf.append({"path": path, "mode": mode, "nelems": nelems,
                          "wire_bytes": b})
         per_mode[mode] = per_mode.get(mode, 0.0) + b

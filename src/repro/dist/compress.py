@@ -160,18 +160,19 @@ def _compressed_allreduce_mean(v, axis_name, mode, two_level=True):
         deq = payload.astype(jnp.float32) * scale
     if n == 1:
         return deq, deq
-    flat = payload.reshape(-1)
-    pad = (-flat.size) % n
-    if pad:
-        flat = jnp.concatenate([flat, jnp.zeros((pad,), flat.dtype)])
+    # Shards are blocks of leading-dim rows (padded to a multiple of n), so
+    # the leaf keeps its own layout: flattening a narrow (rows, D) leaf
+    # forces a relayout that pads D to 128 lanes on TPU.
+    x = _rows_padded(payload, n)
+    k = x.shape[0] // n
     # phase 1: each device ends up holding every peer's copy of its shard
-    mine = lax.all_to_all(flat.reshape(n, -1), axis_name,
-                          split_axis=0, concat_axis=0)
-    corr = None
+    mine = lax.all_to_all(x, axis_name, split_axis=0, concat_axis=0,
+                          tiled=True).reshape((n, k) + x.shape[1:])
     if mode == "bf16":
         y = jnp.sum(_bf16_from_wire(mine), axis=0) / n
         gathered = lax.all_gather(_bf16_to_wire(y), axis_name, tiled=True)
         out = _bf16_from_wire(gathered)
+        charged = deq
     else:
         shard_sum = jnp.sum(mine.astype(jnp.int32), axis=0)  # exact: ≤ 127·n
         y = shard_sum.astype(jnp.float32) * (scale / n)
@@ -179,16 +180,27 @@ def _compressed_allreduce_mean(v, axis_name, mode, two_level=True):
         q2 = _quant(y, scale2)
         gathered = lax.all_gather(q2, axis_name, tiled=True)
         out = gathered.astype(jnp.float32) * scale2
+        charged = deq
         if two_level:
             r2 = y - q2.astype(jnp.float32) * scale2  # this shard's phase-2 loss
-            corr = lax.dynamic_update_slice(
-                jnp.zeros(flat.shape, jnp.float32), n * r2,
-                (lax.axis_index(axis_name) * y.shape[0],))
-    if pad:
-        out = out[:-pad]
-        corr = corr[:-pad] if corr is not None else None
-    charged = deq if corr is None else deq - corr.reshape(v.shape)
-    return out.reshape(v.shape), charged
+            d = _rows_padded(deq, n)
+            at = lax.axis_index(axis_name) * k
+            own = lax.dynamic_slice_in_dim(d, at, k, axis=0)
+            charged = lax.dynamic_update_slice_in_dim(
+                d, own - n * r2, at, axis=0)
+            charged = charged[:_rows(v)].reshape(v.shape)
+    return out[:_rows(v)].reshape(v.shape), charged
+
+
+def _rows(x) -> int:
+    return x.shape[0] if x.ndim else 1
+
+
+def _rows_padded(x, n):
+    """``x`` as at least 1-d, its leading dim zero-padded to a multiple of n."""
+    x = x.reshape(1) if x.ndim == 0 else x
+    pad = (-x.shape[0]) % n
+    return jnp.pad(x, [(0, pad)] + [(0, 0)] * (x.ndim - 1)) if pad else x
 
 
 def _reduce_leaf(g, e, axis_name, mode, two_level=True):

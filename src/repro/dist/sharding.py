@@ -45,8 +45,7 @@ from typing import Optional, Sequence
 
 import jax
 import numpy as np
-from jax.interpreters import pxla
-from jax.sharding import NamedSharding
+from jax.sharding import AxisType, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 __all__ = [
@@ -130,7 +129,8 @@ def placement_specs(params, placement):
 # What the symbol "dp" means for in-model `constrain` calls, and the size of
 # the model axis for `model_divides`.  `lowerables` (configs/common.py) sets
 # these from the target mesh before tracing; the defaults match a plain
-# ("data", "model") mesh so direct model calls under `with mesh:` also work.
+# ("data", "model") mesh so direct model calls under `jax.set_mesh(mesh)`
+# also work.
 _BATCH_AXES: tuple[str, ...] = ("data",)
 _MODEL_SIZE: int = 1
 
@@ -271,22 +271,16 @@ def tree_shardings(structs, mesh, overrides=None):
 # --------------------------------------------------------- activation pinning
 
 
-def _ambient_mesh():
-    try:
-        mesh = pxla.thread_resources.env.physical_mesh
-    except Exception:  # pragma: no cover - jax internals moved
-        return None
-    return None if mesh.empty else mesh
-
-
-def _manual_axes() -> frozenset:
-    """Axis names currently bound manually (shard_map/pmap bodies) — specs on
-    these would make ``with_sharding_constraint`` fail at lowering time."""
-    try:
-        from jax._src.core import get_axis_env
-        return frozenset(get_axis_env().axis_sizes)
-    except Exception:  # pragma: no cover - jax internals moved
-        return frozenset()
+def _auto_axis_sizes() -> dict[str, int]:
+    """Sizes of the ambient mesh's ``Auto`` axes (``jax.set_mesh``).  Empty
+    outside a mesh; axes bound manually (``shard_map`` bodies) or typed
+    ``Explicit`` are left out — specs on them would make
+    ``with_sharding_constraint`` fail at lowering time."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
+        return {}
+    return {a: n for a, n, t in zip(mesh.axis_names, mesh.axis_sizes,
+                                    mesh.axis_types) if t == AxisType.Auto}
 
 
 def constrain(x, *axes):
@@ -298,11 +292,9 @@ def constrain(x, *axes):
     ambient mesh, inside ``shard_map`` (manual axes), or when a dim cannot
     divide the requested axis group — model code calls this unconditionally.
     """
-    mesh = _ambient_mesh()
-    if mesh is None or not hasattr(x, "shape"):
+    sizes = _auto_axis_sizes()
+    if not sizes or not hasattr(x, "shape"):
         return x
-    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
-    manual = _manual_axes()
     shape = x.shape
     spec: list = [None] * len(shape)
     nontrivial = False
@@ -311,7 +303,7 @@ def constrain(x, *axes):
             continue
         group = _BATCH_AXES if ent == "dp" else (tuple(ent) if isinstance(ent, (tuple, list))
                                                  else (ent,))
-        group = tuple(a for a in group if a in sizes and a not in manual)
+        group = tuple(a for a in group if a in sizes)
         if not group:
             continue
         n = _group_size(group, sizes)

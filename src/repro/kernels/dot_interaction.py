@@ -3,13 +3,13 @@
 DLRM concatenates the bottom-MLP output with all sparse embeddings into
 ``X ∈ (B, F, D)`` and feeds the strictly-lower-triangular entries of
 ``X·Xᵀ`` to the top MLP.  Per batch block this is a small MXU matmul
-(``F×D @ D×F``) followed by a triangle extraction; fusing both keeps the
-``(F, F)`` score matrix in VMEM and writes only the ``F(F-1)/2`` packed
-entries.
+(``F×D @ D×F``); the kernel writes the ``(F, F)`` scores with f32
+accumulation and the wrapper takes the triangle with static indices, a
+gather XLA fuses into the next op (the TPU refuses an in-kernel
+``jnp.take``).
 
 Blocking: grid over batch; each step owns a ``(Bb, F, D)`` VMEM tile.  For
-Criteo-scale DLRM (F=27, D=16..64) a whole batch block is a few KB, so
-``Bb`` is chosen to make the matmul MXU-shaped (Bb·F ≥ 128 rows).
+Criteo-scale DLRM (F=27, D=16..64) a whole batch block is a few KB.
 """
 
 from __future__ import annotations
@@ -24,39 +24,30 @@ from jax.experimental import pallas as pl
 __all__ = ["dot_interaction"]
 
 
-def _kernel(flat_idx_ref, x_ref, out_ref):
+def _kernel(x_ref, out_ref):
     x = x_ref[...]  # (Bb, F, D)
-    scores = jax.lax.dot_general(
+    out_ref[...] = jax.lax.dot_general(
         x, x,
         dimension_numbers=(((2,), (2,)), ((0,), (0,))),
         preferred_element_type=jnp.float32,
     )  # (Bb, F, F)
-    bb, f, _ = scores.shape
-    flat = scores.reshape(bb, f * f)
-    out_ref[...] = jnp.take(flat, flat_idx_ref[...], axis=1).astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_b", "interpret"))
-def dot_interaction(x, *, block_b: int = 8, interpret: bool = True):
+def dot_interaction(x, *, block_b: int = 8, interpret: bool = False):
     """Packed strictly-lower-triangle of batched ``X·Xᵀ``.
 
     Args: x: ``(B, F, D)``.  Returns: ``(B, F*(F-1)//2)``.
-    ``B`` must be divisible by ``block_b`` (ops.py pads).  The packed
-    triangle index vector rides along as a (tiny) replicated input — Pallas
-    kernels cannot close over array constants.
+    ``B`` must be divisible by ``block_b`` (ops.py pads).
     """
     b, f, d = x.shape
-    tri_i, tri_j = np.tril_indices(f, k=-1)
-    flat_idx = jnp.asarray(tri_i * f + tri_j, jnp.int32)
-    p = len(tri_i)
-    return pl.pallas_call(
+    scores = pl.pallas_call(
         _kernel,
         grid=(b // block_b,),
-        in_specs=[
-            pl.BlockSpec((p,), lambda i: (0,)),
-            pl.BlockSpec((block_b, f, d), lambda i: (i, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_b, p), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, p), x.dtype),
+        in_specs=[pl.BlockSpec((block_b, f, d), lambda i: (i, 0, 0))],
+        out_specs=pl.BlockSpec((block_b, f, f), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, f, f), jnp.float32),
         interpret=interpret,
-    )(flat_idx, x)
+    )(x)
+    tri_i, tri_j = np.tril_indices(f, k=-1)
+    return scores[:, tri_i, tri_j].astype(x.dtype)

@@ -2,10 +2,10 @@
 
 These are what models call.  Responsibilities:
   * compute quotient/remainder bucket indices (cheap vector ops XLA fuses);
-  * choose execution path: real Pallas on TPU, ``interpret=True`` elsewhere
-    (this container is CPU-only — interpret mode runs the kernel body in
-    Python and is the validation target), or the jnp reference for configs
-    the kernels don't cover (op="concat", k>2 partitions);
+  * choose execution path: the compiled Pallas kernel, or the jnp
+    reference for configs the kernels don't cover (op="concat", k>2
+    partitions).  Interpret mode is chosen here and only here
+    (``interpret_mode``): on the CPU backend, and nowhere else;
   * handle padding so callers never see blocking constraints.
 """
 
@@ -14,28 +14,24 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ..core.compositional import is_quantized_table as _is_quant
+from ..core.compositional import masked_bag_sum, table_rows
 from . import ref
 from .dot_interaction import dot_interaction as _dot_kernel
-from .embedding_bag import qr_embedding_bag as _bag_kernel
-from .qr_gather import qr_gather as _gather_kernel
-from .qr_gather import qr_gather_quant as _gather_quant_kernel
 from .serve_path import fused_serve_pool as _serve_kernel
 
-__all__ = ["on_tpu", "qr_lookup", "qr_bag_lookup", "serve_bag_pool",
-           "dlrm_interact"]
+__all__ = ["interpret_mode", "qr_lookup", "serve_bag_pool", "dlrm_interact"]
 
 
-def on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def interpret_mode() -> bool:
+    """Pallas interpret mode runs the kernel bodies on the CPU backend (its
+    only way to run them); every other backend compiles them."""
+    return jax.default_backend() == "cpu"
 
 
 def _split_idx(idx, m):
     idx = jnp.asarray(idx, jnp.int32)
     return idx % m, idx // m
-
-
-from ..core.compositional import is_quantized_table as _is_quant
-from ..core.compositional import table_rows
 
 
 def _rows(table) -> int:
@@ -48,73 +44,32 @@ def _meta(table):
                             table["zp"].astype(jnp.float32)], axis=1)
 
 
-def qr_lookup(idx, w_rem, w_quo, *, op: str = "mult", use_kernel: bool = True,
-              interpret: bool | None = None):
+def qr_lookup(idx, w_rem, w_quo, *, op: str = "mult", use_kernel: bool = True):
     """QR-trick embedding lookup for arbitrary-rank ``idx``.
 
-    Tables may be dense arrays or row-quantized dicts (``serve.quantize``);
-    when both are quantized the fused dequant kernel gathers the int8 rows
-    and dequantizes in VMEM during the combine.
+    Tables may be dense arrays or row-quantized dicts (``serve.quantize``).
+    On the kernel path each lookup is a one-slot bag of the fused kernel:
+    both rows fetched, dequantized (int8) and combined in VMEM in f32.
     """
     m = _rows(w_rem)
     rem, quo = _split_idx(idx, m)
-    if _is_quant(w_rem) or _is_quant(w_quo):
-        if use_kernel and op in ("mult", "add") \
-                and _is_quant(w_rem) and _is_quant(w_quo):
-            interpret = (not on_tpu()) if interpret is None else interpret
-            shape = rem.shape
-            out = _gather_quant_kernel(rem.reshape(-1), quo.reshape(-1),
-                                       w_rem["q"], w_quo["q"],
-                                       _meta(w_rem), _meta(w_quo),
-                                       op=op, interpret=interpret)
-            return out.reshape(*shape, w_rem["q"].shape[1])
-        a, b = table_rows(w_rem, rem), table_rows(w_quo, quo)
-        if op == "concat":
-            return jnp.concatenate([a, b], axis=-1)
-        return a * b if op == "mult" else a + b
-    if not use_kernel or op == "concat":
-        out = ref.qr_gather_ref(rem, quo, w_rem, w_quo, op=op) if op != "concat" \
-            else jnp.concatenate([jnp.take(w_rem, rem, axis=0),
-                                  jnp.take(w_quo, quo, axis=0)], axis=-1)
-        return out
-    interpret = (not on_tpu()) if interpret is None else interpret
-    shape = rem.shape
-    out = _gather_kernel(rem.reshape(-1), quo.reshape(-1), w_rem, w_quo,
-                         op=op, interpret=interpret)
-    return out.reshape(*shape, w_rem.shape[1])
-
-
-def qr_bag_lookup(idx, mask, w_rem, w_quo, *, op: str = "mult",
-                  use_kernel: bool = True, interpret: bool | None = None):
-    """Sum-pooled multi-hot QR lookup: idx/mask ``(B, L)`` -> ``(B, D)``."""
-    m = _rows(w_rem)
-    rem, quo = _split_idx(idx, m)
-    if _is_quant(w_rem) or _is_quant(w_quo):
-        # quantized bag path: dequantized rows combined per the op, pooled
-        # in f32 (same audit convention as the dense kernel); rows come out
-        # f32 so no cast back is needed
-        a, b = table_rows(w_rem, rem), table_rows(w_quo, quo)
-        if op == "concat":
-            rows = jnp.concatenate([a, b], axis=-1)
-        else:
-            rows = a * b if op == "mult" else a + b
-        return (rows * mask[..., None].astype(jnp.float32)).sum(axis=1)
-    if not use_kernel or op == "concat":
-        if op == "concat":
-            # pool in f32: a bf16 running sum rounds every one of the L adds
-            # (the bug the embedding-bag kernel audit caught at L=16, D=128)
-            rows = jnp.concatenate([jnp.take(w_rem, rem, axis=0),
-                                    jnp.take(w_quo, quo, axis=0)],
-                                   axis=-1).astype(jnp.float32)
-            pooled = (rows * mask[..., None].astype(jnp.float32)).sum(axis=1)
-            return pooled.astype(w_rem.dtype)
-        return ref.qr_embedding_bag_ref(rem, quo, mask, w_rem, w_quo, op=op)
-    interpret = (not on_tpu()) if interpret is None else interpret
-    return _bag_kernel(rem, quo, mask, w_rem, w_quo, op=op, interpret=interpret)
+    quant = _is_quant(w_rem)
+    if use_kernel and op in ("mult", "add") and quant == _is_quant(w_quo):
+        out = _serve_kernel(
+            rem.reshape(-1, 1), None, w_rem["q"] if quant else w_rem,
+            idx_b=quo.reshape(-1, 1), w_b=w_quo["q"] if quant else w_quo,
+            meta_a=_meta(w_rem) if quant else None,
+            meta_b=_meta(w_quo) if quant else None,
+            op=op, interpret=interpret_mode())
+        return out.reshape(*rem.shape, out.shape[-1])
+    a, b = table_rows(w_rem, rem), table_rows(w_quo, quo)
+    if op == "concat":
+        return jnp.concatenate([a, b], axis=-1)
+    return a * b if op == "mult" else a + b
 
 
 def serve_bag_pool(idx, mask, w_a, w_b=None, *, op: str = "mult", proj=None,
-                   use_kernel: bool = True, interpret: bool | None = None):
+                   use_kernel: bool = True):
     """Serving hot-path pooled lookup: gather (+dequant) → pool → project.
 
     The single entry point the serving stack routes through.  ``w_a`` (and
@@ -124,7 +79,7 @@ def serve_bag_pool(idx, mask, w_a, w_b=None, *, op: str = "mult", proj=None,
     (full / hash / the engine's device-resident row slab) pass pre-folded
     indices.  ``proj`` is the mixed-dimension ``(d, D)`` projection —
     pooling and projection fuse into the same VMEM pass on the kernel
-    path, and the jnp fallback (non-TPU, or op="concat"/mixed-quant pairs
+    path, and the jnp fallback (op="concat"/mixed-quant pairs
     the kernel doesn't cover) computes the identical math via the
     ``kernels.ref`` oracle.
     """
@@ -141,10 +96,9 @@ def serve_bag_pool(idx, mask, w_a, w_b=None, *, op: str = "mult", proj=None,
     ma = _meta(w_a) if quant_a else None
     mb = _meta(w_b) if (w_b is not None and quant_b) else None
     if use_kernel and fusable:
-        interpret = (not on_tpu()) if interpret is None else interpret
         return _serve_kernel(idx_a, mask, qa, idx_b=idx_b, w_b=qb,
                              meta_a=ma, meta_b=mb, proj=proj, op=op,
-                             interpret=interpret)
+                             interpret=interpret_mode())
     if not fusable:
         # op="concat" / mixed dense+quant pair: gather per table, combine,
         # pool in f32, project — same contract, jnp all the way
@@ -152,25 +106,22 @@ def serve_bag_pool(idx, mask, w_a, w_b=None, *, op: str = "mult", proj=None,
         b = table_rows(w_b, idx_b)
         rows = (jnp.concatenate([a, b], axis=-1) if op == "concat"
                 else (a * b if op == "mult" else a + b))
-        pooled = (rows.astype(jnp.float32)
-                  * mask[..., None].astype(jnp.float32)).sum(axis=1)
         quant = quant_a or quant_b
-        pooled = pooled.astype(jnp.float32 if quant else a.dtype)
+        pooled = masked_bag_sum(rows, mask).astype(
+            jnp.float32 if quant else a.dtype)
         return pooled if proj is None \
             else pooled.astype(jnp.float32) @ proj.astype(jnp.float32)
     return ref.fused_serve_pool_ref(idx_a, mask, qa, idx_b=idx_b, w_b=qb,
                                     meta_a=ma, meta_b=mb, proj=proj, op=op)
 
 
-def dlrm_interact(x, *, use_kernel: bool = True, interpret: bool | None = None,
-                  block_b: int = 8):
+def dlrm_interact(x, *, use_kernel: bool = True, block_b: int = 8):
     """DLRM pairwise-dot interaction, padding batch to the kernel block."""
     if not use_kernel:
         return ref.dot_interaction_ref(x)
-    interpret = (not on_tpu()) if interpret is None else interpret
     b = x.shape[0]
     pad = (-b) % block_b
     if pad:
         x = jnp.concatenate([x, jnp.zeros((pad,) + x.shape[1:], x.dtype)], axis=0)
-    out = _dot_kernel(x, block_b=block_b, interpret=interpret)
+    out = _dot_kernel(x, block_b=block_b, interpret=interpret_mode())
     return out[:b]

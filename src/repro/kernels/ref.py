@@ -5,6 +5,8 @@ from __future__ import annotations
 import jax.numpy as jnp
 import numpy as np
 
+from ..core.compositional import masked_bag_sum
+
 __all__ = ["qr_gather_ref", "qr_gather_quant_ref", "qr_embedding_bag_ref",
            "fused_serve_pool_ref", "dot_interaction_ref"]
 
@@ -34,9 +36,7 @@ def qr_embedding_bag_ref(rem_idx, quo_idx, mask, w_rem, w_quo, *, op: str = "mul
     # oracle must not inherit the bf16 running-sum rounding it exists to
     # catch in the kernels.  Result is cast back to the table dtype.
     rows = qr_gather_ref(rem_idx, quo_idx, w_rem, w_quo, op=op)  # (B, L, D)
-    pooled = (rows.astype(jnp.float32)
-              * mask[..., None].astype(jnp.float32)).sum(axis=1)
-    return pooled.astype(w_rem.dtype)
+    return masked_bag_sum(rows, mask).astype(w_rem.dtype)
 
 
 def fused_serve_pool_ref(idx_a, mask, w_a, idx_b=None, w_b=None, meta_a=None,
@@ -65,8 +65,8 @@ def fused_serve_pool_ref(idx_a, mask, w_a, idx_b=None, w_b=None, meta_a=None,
     if idx_b is not None:
         rb = rows(w_b, meta_b, idx_b)
         row = row * rb if op == "mult" else row + rb
-    pooled = (row * mask[..., None].astype(jnp.float32)).sum(axis=1)
-    pooled = pooled.astype(jnp.float32 if quant else w_a.dtype)
+    pooled = masked_bag_sum(row, mask).astype(
+        jnp.float32 if quant else w_a.dtype)
     if proj is None:
         return pooled
     return pooled.astype(jnp.float32) @ proj.astype(jnp.float32)

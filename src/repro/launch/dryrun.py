@@ -61,7 +61,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, out_dir: str,
         # without donation XLA double-buffers multi-GB state trees.
         donate = {"train": (0,), "prefill": (len(args) - 1,),
                   "decode": (3,)}[kind]
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = jax.jit(fn, donate_argnums=donate).lower(*args)
             record["time_lower_s"] = round(time.monotonic() - t0, 2)
             t1 = time.monotonic()

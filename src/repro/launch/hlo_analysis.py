@@ -331,8 +331,6 @@ def analyze_compiled(compiled, total_devices: int = 1) -> dict:
     xla = {}
     try:
         ca = compiled.cost_analysis()
-        if isinstance(ca, list):  # older jax returns [dict] per program
-            ca = ca[0] if ca else {}
         xla = {k: float(v) for k, v in ca.items()
                if isinstance(v, (int, float)) and k in ("flops", "bytes accessed")}
     except Exception as e:  # pragma: no cover

@@ -13,6 +13,10 @@ speaks the LM prefill/decode interface:
   (``--mesh-devices N``: plan-aware placement, remote rows over the
   all-to-all exchange), and report table bytes, p50/p99 latency, QPS,
   and cache hit rate.
+
+``--no-reduced`` serves the full-size config (Kaggle cardinalities for the
+rec archs).  ``main(argv)`` returns the drained engine, so one process can
+train and then serve (a chip belongs to one process at a time).
 """
 
 import argparse
@@ -20,12 +24,15 @@ import time
 
 import jax
 
+from .compile_cache import enable_compile_cache
+from .mesh import make_mesh
+
 
 def _serve_lm(mod, args):
     from ..configs.common import Shape
     from ..serve.engine import ServeEngine
 
-    cfg = mod.config(reduced=True)
+    cfg = mod.config(reduced=args.reduced)
     api = mod.api(cfg)
     if api.prefill is None or api.decode is None:
         raise SystemExit(f"{args.arch} has no LM serving path")
@@ -59,11 +66,26 @@ def _serve_lm(mod, args):
     print(f"{args.arch}: served {len(done)} requests / {toks} tokens in {dt:.2f}s")
     for uid in sorted(done)[:3]:
         print(f"  req {uid}: {done[uid].output}")
+    return engine
+
+
+def synthetic_requests(cfg, n: int, max_bag: int, seed: int = 0):
+    """``n`` seeded ``(dense, bags)`` requests: one bag of 1..max_bag ids
+    per table, Zipf-skewed towards low ids (the criteo generator's skew)."""
+    import numpy as np
+    rng = np.random.default_rng(seed)
+    out = []
+    for _ in range(n):
+        dense = rng.normal(size=cfg.dense_dim)
+        bags = []
+        for s in cfg.table_sizes:
+            u = rng.random(int(rng.integers(1, max_bag + 1)))
+            bags.append(list((np.floor((u ** 1.5) * s)).astype(np.int64)))
+        out.append((dense, bags))
+    return out
 
 
 def _serve_rec(mod, args):
-    import numpy as np
-
     from ..serve.cache import DeviceHotRowCache, HotRowCache
     from ..serve.quantize import memory_report, quantize_params
     from ..serve.recsys import RecsysEngine
@@ -77,8 +99,8 @@ def _serve_rec(mod, args):
         obs = Obs(trace=bool(args.trace), collisions=True)
 
     plan = resolve_plan_args(mod, args)
-    cfg = (mod.config(reduced=True, plan=plan) if plan is not None
-           else mod.config(reduced=True))
+    cfg = (mod.config(reduced=args.reduced, plan=plan) if plan is not None
+           else mod.config(reduced=args.reduced))
     api = mod.api(cfg)
     params = api.init(jax.random.PRNGKey(0))
     qparams = quantize_params(params, mode=args.quantize)
@@ -128,7 +150,7 @@ def _serve_rec(mod, args):
               f"{pl.n_devices} devices, "
               f"{rep['placement']['table_bytes_per_device']} B/device")
     else:
-        mesh = jax.make_mesh((jax.device_count(), 1), ("data", "model"))
+        mesh = make_mesh((jax.device_count(), 1), ("data", "model"))
         engine = RecsysEngine(cfg, qparams, max_batch=args.batch_size,
                               cache=cache, mesh=mesh,
                               batching=args.batching, obs=obs)
@@ -153,19 +175,11 @@ def _serve_rec(mod, args):
         print(f"  replan: every {args.replan_interval} requests, "
               f"budget {budget} B")
 
-    # Zipfian synthetic request stream (the criteo generator's skew)
-    rng = np.random.default_rng(0)
-    sizes = cfg.table_sizes
+    requests = synthetic_requests(cfg, args.requests, args.max_bag)
     done = {}
     interval = args.replan_interval or args.requests
     for start in range(0, args.requests, interval):
-        for _ in range(start, min(start + interval, args.requests)):
-            dense = rng.normal(size=cfg.dense_dim)
-            bags = []
-            for s in sizes:
-                ln = int(rng.integers(1, args.max_bag + 1))
-                u = rng.random(ln)
-                bags.append(list((np.floor((u ** 1.5) * s)).astype(np.int64)))
+        for dense, bags in requests[start:start + interval]:
             engine.submit(dense, bags)
         done.update(engine.run_until_drained())
         if ctrl is not None:
@@ -182,6 +196,7 @@ def _serve_rec(mod, args):
     print(f"{args.arch}: served {len(done)} requests in {m['waves']} waves | "
           f"p50 {m['p50_ms']:.1f} ms  p99 {m['p99_ms']:.1f} ms  "
           f"qps {m['qps']:.1f}")
+    print(f"  wave paths: {m['paths']}")
     if cache is not None:
         print(f"  cache: hit_rate {m['cache']['hit_rate']:.3f} "
               f"({m['cache']['hits']}/{m['cache']['lookups']}), "
@@ -198,11 +213,15 @@ def _serve_rec(mod, args):
                 print(f"  obs: wrote {p}")
     for uid in sorted(done)[:3]:
         print(f"  req {uid}: score {done[uid].score:+.4f}")
+    return engine
 
 
-def main():
+def main(argv=None):
+    """Parse ``argv`` (default ``sys.argv[1:]``), serve, return the engine."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tinyllama-1.1b")
+    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--no-reduced", dest="reduced", action="store_false")
     ap.add_argument("--requests", type=int, default=12)
     ap.add_argument("--batch-size", type=int, default=4)
     # LM knobs
@@ -255,14 +274,14 @@ def main():
                          "f32 table footprint when serving unplanned)")
     from .plan_cli import add_plan_args
     add_plan_args(ap)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
+    enable_compile_cache()
     from ..configs import get_arch
     mod = get_arch(args.arch)
     if getattr(mod, "FAMILY", "lm") == "rec":
-        _serve_rec(mod, args)
-    else:
-        _serve_lm(mod, args)
+        return _serve_rec(mod, args)
+    return _serve_lm(mod, args)
 
 
 if __name__ == "__main__":

@@ -1,20 +1,26 @@
 """Training launcher: ``python -m repro.launch.train --arch <id> [...]``.
 
-On this CPU container it runs reduced configs end-to-end (the same code
-path the production mesh lowers — pjit step, sharded loader, async
-checkpoints, restart-safe).  On a real cluster the only changes are
-``--mesh`` and full-scale ``--no-reduced``.
+Reduced configs by default; ``--no-reduced`` trains the full-size config
+(Kaggle cardinalities for the rec archs) through the same code path —
+jitted step, sharded loader, async checkpoints, restart-safe.
+``main(argv)`` returns the
+final state, the logged ``(step, loss)`` history and the per-step wall
+times, so one process can train and then serve.
 """
 
 import argparse
 
 import jax
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
+from .compile_cache import enable_compile_cache
+from .mesh import make_mesh
 from .plan_cli import add_plan_args, resolve_plan_args
 
 
-def main():
+def main(argv=None):
+    """Parse ``argv`` (default ``sys.argv[1:]``) and train."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="dlrm-criteo")
     ap.add_argument("--steps", type=int, default=200)
@@ -37,7 +43,8 @@ def main():
                     help="write the metrics registry as JSONL to PATH "
                          "(implies obs on)")
     add_plan_args(ap)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     from ..configs import get_arch
     from ..configs.common import Shape
@@ -66,9 +73,13 @@ def main():
         if args.batch % n_dev:
             raise SystemExit(f"--batch {args.batch} must be a multiple of "
                              f"the device count {n_dev} for the dp step")
-        mesh = jax.make_mesh((n_dev,), ("data",))
-        state = init_dp_state(params, api.optimizer,
-                              compress=args.compress_policy)
+        mesh = make_mesh((n_dev,), ("data",))
+        # replicated on the mesh from the start, as every step returns it:
+        # a state on one device would recompile the step at step 1
+        state = jax.device_put(
+            init_dp_state(params, api.optimizer,
+                          compress=args.compress_policy),
+            NamedSharding(mesh, PartitionSpec()))
         step = make_dp_train_step(api.loss_fn, api.optimizer, mesh,
                                   compress=args.compress_policy)
         print(f"dp step over {n_dev} device(s), "
@@ -100,6 +111,8 @@ def main():
         for p in (args.metrics_out, args.trace):
             if p:
                 print(f"obs: wrote {p}")
+    return {"state": state, "history": history,
+            "step_seconds": trainer.step_seconds}
 
 
 if __name__ == "__main__":

@@ -29,8 +29,8 @@ A quantized table is a plain pytree: ``{"q": int8 (rows, D), "scale":
 bf16 (rows, 1), "zp": int8 (rows, 1)}`` — it jits, shards (the rule
 engine's ``table_\\d+`` pattern matches the parent path), and
 checkpoints like any other params.  Lookups dequantize only the gathered
-rows (``core.compositional.table_rows``); the fused Pallas path
-(``kernels.qr_gather.qr_gather_quant``) does the dequant in VMEM during
+rows (``core.compositional.table_rows``); the fused Pallas kernel
+(``kernels.serve_path.fused_serve_pool``) does the dequant in VMEM during
 the combine.
 
 ``mode="bf16"`` is the cheap alternative: matching leaves are cast to

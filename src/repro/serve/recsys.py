@@ -44,7 +44,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from collections import deque
+from collections import Counter, deque
 from typing import Optional, Sequence
 
 import jax
@@ -52,7 +52,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core import CompositionalEmbedding, HashEmbedding
-from ..core.compositional import is_quantized_table
+from ..core.compositional import is_quantized_table, masked_bag_sum
 from ..models.dcn import DCNConfig, dcn_forward_from_features
 from ..models.dlrm import (DLRMConfig, dlrm_forward_from_features,
                            embed_features, tables_for)
@@ -163,6 +163,10 @@ class RecsysEngine:
         self.completed: dict[int, RecRequest] = {}
         self.wave_latencies_s: list[float] = []
         self.wave_sizes: list[int] = []
+        # waves per embed path ("fast" slot-map probe, "exact" device-cache
+        # lookup, "host_cache", "in_graph", "sharded*"); "*_miss" counts
+        # speculative probes that missed and were recomputed exactly
+        self.wave_paths: Counter = Counter()
         self.buckets_seen: set[tuple[int, int]] = set()
         self._t_first: Optional[float] = None
         self._t_last: Optional[float] = None
@@ -240,8 +244,8 @@ class RecsysEngine:
             for i in range(len(feat_width)):
                 rows = jnp.take(slabs[w_index[feat_width[i]]],
                                 slots[:, i, :], axis=0)      # (B, L, d_i)
-                pooled = (rows * mask[:, i, :, None].astype(jnp.float32)
-                          ).sum(axis=1).astype(row_dtypes[i])
+                pooled = masked_bag_sum(rows, mask[:, i, :]
+                                        ).astype(row_dtypes[i])
                 w = proj.get(str(i))
                 feats.append(pooled if w is None else pooled @ w)
             return jnp.stack(feats, axis=1)
@@ -374,7 +378,8 @@ class RecsysEngine:
                 f"mesh_devices={n} but only {jax.device_count()} devices "
                 "visible (CI emulates via --xla_force_host_platform_"
                 "device_count)")
-        self._serve_mesh = jax.make_mesh((n,), ("data",))
+        from ..launch.mesh import make_mesh
+        self._serve_mesh = make_mesh((n,), ("data",))
         if placement is None:
             placement = plan_placement(params, n, plan=plan)
         if placement.n_devices != n:
@@ -399,7 +404,7 @@ class RecsysEngine:
         and tests assert it).  Row-sharded sub-tables fetch rows through
         the two-phase all-to-all exchange (``dist.serve_placement.
         exchange_rows``); everything else is local."""
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from ..core.compositional import bag_pool, table_rows
@@ -444,9 +449,8 @@ class RecsysEngine:
                 if repl[i]:
                     rows = jnp.take(slabs[w_index[feat_width[i]]],
                                     slots[:, i, :], axis=0)
-                    pooled = (rows * mask[:, i, :, None]
-                              .astype(jnp.float32)).sum(axis=1) \
-                        .astype(row_dtypes[i])
+                    pooled = masked_bag_sum(rows, mask[:, i, :]
+                                            ).astype(row_dtypes[i])
                     feats.append(_project(pooled, proj, i))
                     nmiss = nmiss + jnp.sum((slots[:, i, :] < 0)
                                             & (mask[:, i, :] > 0))
@@ -511,9 +515,10 @@ class RecsysEngine:
 
     def _dispatch_sharded(self, dense, idx, mask):
         """Dispatch one wave through the sharded programs; returns
-        ``(logits, check, ta)`` with the same speculative-probe contract
-        as the single-host device-cache path (``ta`` is the probe/dense
-        stage boundary timestamp, None when obs is off)."""
+        ``(logits, check, ta, path)`` with the same speculative-probe
+        contract as the single-host device-cache path (``ta`` is the
+        probe/dense stage boundary timestamp, None when obs is off; ``path``
+        names the embed path for ``wave_paths``)."""
         check = None
         if (isinstance(self.cache, DeviceHotRowCache)
                 and not self.cache.record_events
@@ -524,14 +529,16 @@ class RecsysEngine:
                 self.params, jnp.asarray(np.asarray(idx, np.int32)),
                 jnp.asarray(mask), smap, slabs)
             check = (dense, idx, mask, nmiss)
+            path = "sharded_fast"
         else:
             if self.cache is not None:
                 self._admit_cacheable(idx, mask)
             feats = self._sharded_embed(self.params, jnp.asarray(idx),
                                         jnp.asarray(mask))
+            path = "sharded"
         ta = time.monotonic() if self._obs is not None else None
         logits = self._sharded_dense(self.params, jnp.asarray(dense), feats)
-        return logits, check, ta
+        return logits, check, ta, path
 
     # ------------------------------------------------------------- intake
 
@@ -826,7 +833,8 @@ class RecsysEngine:
             obs.collisions.record(idx, mask, live_rows=len(wave))
         check = None
         if self._n_shards > 1:
-            logits, check, ta = self._dispatch_sharded(dense, idx, mask)
+            logits, check, ta, path = self._dispatch_sharded(dense, idx,
+                                                             mask)
         else:
             if isinstance(self.cache, DeviceHotRowCache):
                 fast = None if self.cache.record_events \
@@ -834,13 +842,17 @@ class RecsysEngine:
                 if fast is not None:
                     feats, nmiss = fast
                     check = (dense, idx, mask, nmiss)
+                    path = "fast"
                 else:
                     feats = self._embed_device(idx, mask)
+                    path = "exact"
             elif self.cache is not None:
                 feats = jnp.asarray(self._embed_cached(idx, mask))
+                path = "host_cache"
             else:
                 feats = self._embed_fwd(self.params, jnp.asarray(idx),
                                         jnp.asarray(mask))
+                path = "in_graph"
             ta = time.monotonic() if obs is not None else None
             logits = self._dense_fwd(self.params, jnp.asarray(dense), feats)
         self._t_first = t0 if self._t_first is None else self._t_first
@@ -861,15 +873,17 @@ class RecsysEngine:
                         bucket[1])["total_bytes"])
                     self._wire_by_bucket[bucket] = wb
                 self._c_wire.inc(wb)
-        self._inflight.append((wave, logits, t0, check, oi))
+        self._inflight.append((wave, logits, t0, check, oi, path))
 
     def _reap(self) -> list[RecRequest]:
-        wave, logits, t0, check, oi = self._inflight.popleft()
+        wave, logits, t0, check, oi, path = self._inflight.popleft()
         tc = time.monotonic() if oi is not None else None
         if check is not None:
             # settle the speculative probe: by reap time the async miss
             # count has materialized, so this blocks on nothing extra
             dense, idx, mask, nmiss = check
+            if int(nmiss):
+                path += "_miss"
             if int(nmiss) and self._n_shards > 1:
                 # some cacheable row was not resident: admit it with exact
                 # accounting, then recompute through the pure programs
@@ -893,6 +907,7 @@ class RecsysEngine:
         self._t_last = t1
         self.wave_latencies_s.append(t1 - t0)
         self.wave_sizes.append(len(wave))
+        self.wave_paths[path] += 1
         if oi is not None:
             self._record_wave(oi, tc, td, t1)
         for b, r in enumerate(wave):  # padded rows beyond len(wave) discarded
@@ -978,6 +993,7 @@ class RecsysEngine:
         reset too; bound label handles stay live."""
         self.wave_latencies_s = []
         self.wave_sizes = []
+        self.wave_paths = Counter()
         self._t_first = self._t_last = None
         if self.cache is not None:
             self.cache.stats = CacheStats(
@@ -1025,6 +1041,7 @@ class RecsysEngine:
             "p99_ms": float(np.percentile(lat, 99) * 1e3),
             "qps": (sum(self.wave_sizes) / wall) if wall > 0 else 0.0,
             "buckets": sorted(self.buckets_seen),
+            "paths": dict(self.wave_paths),
         }
         if self.cache is not None:
             out["cache"] = self.cache.stats.as_dict()

@@ -135,7 +135,7 @@ def make_dp_train_step(loss_fn, optimizer: Optimizer, mesh, *,
     per-leaf mode pytree.  The per-replica update math is identical, so
     replicas stay bitwise consistent without re-broadcast.
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     compress = _resolve_compress(compress)
 
     def _step(state, batch):
@@ -157,7 +157,7 @@ def make_dp_train_step(loss_fn, optimizer: Optimizer, mesh, *,
     return shard_map(_step, mesh=mesh,
                      in_specs=(P(), P(axis)),
                      out_specs=(P(), P()),
-                     check_rep=False)
+                     check_vma=False)
 
 
 def init_dp_state(params, optimizer: Optimizer, compress=None):
@@ -236,7 +236,7 @@ def make_fsdp_train_step(loss_fn, optimizer: Optimizer, mesh, params_like, *,
     precision; only the replicated copies of **other** devices' shards
     are bf16-rounded (one bf16 ulp on the forward, ~2^-9 relative).
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     n = _axis_size(mesh, axis)
     gather_bf16 = jnp.dtype(param_gather_dtype) == jnp.bfloat16
     if not gather_bf16 and jnp.dtype(param_gather_dtype) != jnp.float32:
@@ -328,7 +328,7 @@ def make_fsdp_train_step(loss_fn, optimizer: Optimizer, mesh, params_like, *,
     return shard_map(_step, mesh=mesh,
                      in_specs=(state_specs, P(axis)),
                      out_specs=(state_specs, P()),
-                     check_rep=False)
+                     check_vma=False)
 
 
 def init_fsdp_state(params, optimizer: Optimizer, mesh, *, policy="auto",
@@ -366,6 +366,7 @@ class Trainer:
         self.checkpointer = (ckpt.AsyncCheckpointer(cfg.ckpt_dir, keep=cfg.keep)
                              if cfg.ckpt_dir else None)
         self.straggler_events: list[tuple[int, float]] = []
+        self.step_seconds: list[float] = []   # wall time of each run() step
         self._obs = obs
         if obs is not None:
             self._h_step = obs.histogram(
@@ -402,7 +403,7 @@ class Trainer:
     def run(self, state, *, fail_at_step: Optional[int] = None):
         cfg = self.cfg
         history = []
-        durations: list[float] = []
+        durations = self.step_seconds
         start = int(state["step"])
         for step in range(start, cfg.num_steps):
             if fail_at_step is not None and step == fail_at_step:

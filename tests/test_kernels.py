@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import dlrm_interact, qr_bag_lookup, qr_lookup
+from repro.kernels import dlrm_interact, qr_lookup, serve_bag_pool
 from repro.kernels import ref
 
 DTYPES = [jnp.float32, jnp.bfloat16]
@@ -39,7 +39,7 @@ def test_qr_bag_sweep(dtype, b, l, m, q, d):
     wr, wq = _tables(jax.random.PRNGKey(2), m, q, d, dtype)
     idx = jax.random.randint(jax.random.PRNGKey(3), (b, l), 0, m * q)
     mask = (jax.random.uniform(jax.random.PRNGKey(4), (b, l)) > 0.3).astype(dtype)
-    got = qr_bag_lookup(idx, mask, wr, wq, op="mult")
+    got = serve_bag_pool(idx, mask, wr, wq, op="mult")
     want = ref.qr_embedding_bag_ref(idx % m, idx // m, mask, wr, wq, op="mult")
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), **TOL[dtype])
@@ -101,7 +101,7 @@ def test_bag_accumulates_f32_at_L16_D128(op, use_kernel):
     wr, wq = jnp.abs(wr) + 0.5, jnp.abs(wq) + 0.5
     idx = jax.random.randint(jax.random.PRNGKey(11), (AUDIT_B, AUDIT_L), 0, m * q)
     mask = jnp.ones((AUDIT_B, AUDIT_L), jnp.bfloat16)
-    got = qr_bag_lookup(idx, mask, wr, wq, op=op, use_kernel=use_kernel)
+    got = serve_bag_pool(idx, mask, wr, wq, op=op, use_kernel=use_kernel)
     want = _audit_f32_oracle(idx, mask, wr, wq, op)
     np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want),
                                rtol=5e-3, atol=0)
@@ -113,7 +113,7 @@ def test_bag_concat_accumulates_f32_at_L16_D128():
     wr, wq = jnp.abs(wr) + 0.5, jnp.abs(wq) + 0.5
     idx = jax.random.randint(jax.random.PRNGKey(13), (AUDIT_B, AUDIT_L), 0, m * q)
     mask = jnp.ones((AUDIT_B, AUDIT_L), jnp.bfloat16)
-    got = qr_bag_lookup(idx, mask, wr, wq, op="concat")
+    got = serve_bag_pool(idx, mask, wr, wq, op="concat")
     rows = jnp.concatenate([jnp.take(wr.astype(jnp.float32), idx % m, axis=0),
                             jnp.take(wq.astype(jnp.float32), idx // m, axis=0)],
                            axis=-1)

@@ -17,6 +17,7 @@ from repro.dist import accounting
 from repro.dist.compress import (ef_psum_grads, init_error_state,
                                  resolve_modes)
 from repro.dist.policy import AUTO, CompressionPolicy, resolve_policy
+from repro.launch.mesh import make_mesh
 from repro.optim.optimizers import leaf_paths
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -169,7 +170,7 @@ def test_tree_accounting_int8_policy_under_0p3():
 def test_fsdp_accounting_reports_param_gather():
     from repro.optim.optimizers import adagrad
     params = _params()
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     # trivial mesh: no wire at all
     acct = accounting.fsdp_step_wire_bytes(params, adagrad(1e-2), mesh, AUTO)
     assert acct["total_bytes"] == 0.0
@@ -189,13 +190,13 @@ def test_fsdp_step_preserves_rank0_leaves():
         return loss, {"mse": loss}
 
     params = {"w": jnp.full((16, 8), 0.1), "temp": jnp.float32(1.0)}
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     opt = adagrad(1e-2)
     state = init_fsdp_state(params, opt, mesh, policy="auto")
     step = jax.jit(make_fsdp_train_step(loss_fn, opt, mesh, params,
                                         policy="auto"))
     b = {"x": jnp.ones((4, 16)), "y": jnp.zeros((4, 8))}
-    with mesh:
+    with jax.set_mesh(mesh):
         for _ in range(2):
             state, m = step(state, b)
     assert state["params"]["temp"].shape == ()

@@ -185,12 +185,13 @@ _CHILD = textwrap.dedent("""
     import dataclasses, json
     import numpy as np
     import jax, jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.configs import dlrm_criteo
     from repro.core.compositional import table_rows
     from repro.dist.serve_placement import exchange_rows, plan_placement
+    from repro.launch.mesh import make_mesh
     from repro.plan import plan_for_config
     from repro.serve.cache import DeviceHotRowCache
     from repro.serve.quantize import quantize_params
@@ -198,7 +199,7 @@ _CHILD = textwrap.dedent("""
 
     out = {}
     n = 8
-    mesh = jax.make_mesh((n,), ("data",))
+    mesh = make_mesh((n,), ("data",))
 
     # --- exchange_rows vs local table_rows: bitwise, f32 and quantized
     rng = np.random.default_rng(0)

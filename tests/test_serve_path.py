@@ -88,7 +88,8 @@ def test_fused_kernel_matches_oracle_grid(mode, l):
                 pairs.append(dict(idx_a=idx % m))
             for kw in pairs:
                 got = fused_serve_pool(mask=mask, w_a=wa, meta_a=ma,
-                                       proj=proj, op="mult", **kw)
+                                       proj=proj, op="mult", interpret=True,
+                                       **kw)
                 want = ref.fused_serve_pool_ref(mask=mask, w_a=wa,
                                                 meta_a=ma, proj=proj,
                                                 op="mult", **kw)
@@ -108,16 +109,17 @@ def test_fused_kernel_add_op_and_validation():
     wa, wb, ma, mb = _tables(jax.random.PRNGKey(0), 8, 4, 16, "int8")
     idx, mask = _bags(jax.random.PRNGKey(1), 2, 5, 32)
     got = fused_serve_pool(idx % 8, mask, wa, idx_b=idx // 8, w_b=wb,
-                           meta_a=ma, meta_b=mb, op="add")
+                           meta_a=ma, meta_b=mb, op="add", interpret=True)
     want = ref.fused_serve_pool_ref(idx % 8, mask, wa, idx_b=idx // 8,
                                     w_b=wb, meta_a=ma, meta_b=mb, op="add")
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
     with pytest.raises(ValueError, match="pairs"):
-        fused_serve_pool(idx % 8, mask, wa, idx_b=idx // 8, w_b=None)
+        fused_serve_pool(idx % 8, mask, wa, idx_b=idx // 8, w_b=None,
+                         interpret=True)
     with pytest.raises(ValueError, match="pairs"):
         fused_serve_pool(idx % 8, mask, wa, idx_b=idx // 8, w_b=wb,
-                         meta_a=ma, meta_b=None)
+                         meta_a=ma, meta_b=None, interpret=True)
 
 
 def test_serve_bag_pool_routing():

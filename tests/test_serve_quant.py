@@ -9,7 +9,8 @@ import pytest
 
 from repro.core import EmbeddingSpec, table_rows
 from repro.kernels import ops, ref
-from repro.kernels.qr_gather import qr_gather_quant
+from repro.kernels.serve_path import fused_serve_pool
+from repro.launch.mesh import make_mesh
 from repro.models.dcn import DCNConfig, dcn_init
 from repro.models.dlrm import (DLRMConfig, dlrm_forward, dlrm_init,
                                dlrm_loss_fn)
@@ -134,7 +135,9 @@ def test_qr_gather_quant_kernel_matches_oracle(op, m, q, d, n):
                               qr_["zp"].astype(jnp.float32)], axis=1)
     meta_q = jnp.concatenate([qq_["scale"].astype(jnp.float32),
                               qq_["zp"].astype(jnp.float32)], axis=1)
-    got = qr_gather_quant(rem, quo, qr_["q"], qq_["q"], meta_r, meta_q, op=op)
+    got = fused_serve_pool(rem[:, None], None, qr_["q"], idx_b=quo[:, None],
+                           w_b=qq_["q"], meta_a=meta_r, meta_b=meta_q, op=op,
+                           interpret=True)
     want = ref.qr_gather_quant_ref(rem, quo, qr_["q"], qq_["q"],
                                    meta_r, meta_q, op=op)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
@@ -173,12 +176,12 @@ def test_qr_bag_lookup_quantized_mask_semantics():
     qr_, qq_ = quantize_table(wr), quantize_table(wq)
     idx = jax.random.randint(jax.random.PRNGKey(10), (4, 6), 0, 200)
     mask = jnp.asarray(np.tile([1, 1, 1, 0, 0, 0], (4, 1)), jnp.float32)
-    got = ops.qr_bag_lookup(idx, mask, qr_, qq_)
+    got = ops.serve_bag_pool(idx, mask, qr_, qq_)
     # garbage in the masked tail must not change the pool
     idx_garbage = idx.at[:, 3:].set(199)
-    got2 = ops.qr_bag_lookup(idx_garbage, mask, qr_, qq_)
+    got2 = ops.serve_bag_pool(idx_garbage, mask, qr_, qq_)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(got2))
-    want = ops.qr_bag_lookup(idx[:, :3], mask[:, :3], qr_, qq_)
+    want = ops.serve_bag_pool(idx[:, :3], mask[:, :3], qr_, qq_)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-6)
 
 
@@ -453,7 +456,7 @@ def test_engine_inference_placement_smoke():
     """params placed under INFERENCE_OVERRIDES (mesh path) still serve."""
     cfg = _cfg()
     params = quantize_params(dlrm_init(jax.random.PRNGKey(0), cfg))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     eng = RecsysEngine(cfg, params, max_batch=4, mesh=mesh)
     uid = eng.submit(np.zeros(13), [[1], [2, 3], [4]])
     done = eng.run_until_drained()

@@ -16,12 +16,13 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.launch.hlo_analysis import analyze_hlo
+from repro.launch.mesh import make_mesh
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def test_spec_engine_rules():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     # fake the production sizes by checking divisibility logic directly
     from repro.dist.sharding import spec_for
     # embedding rows -> model
@@ -35,9 +36,10 @@ def test_spec_engine_production_shapes():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, json
+        from repro.launch.mesh import make_mesh
         from jax.sharding import PartitionSpec as P
         from repro.dist.sharding import spec_for
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         out = {}
         out["embed"] = str(spec_for("embed/table_0", (8000, 2048), mesh))
         out["head"] = str(spec_for("lm_head/w", (2048, 32000), mesh))
@@ -67,6 +69,7 @@ def test_mini_dryrun_8dev_train_and_decode():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, json
+        from repro.launch.mesh import make_mesh
         import jax.numpy as jnp
         from repro.configs import get_arch
         from repro.configs.common import lowerables, SHAPES, Shape
@@ -76,7 +79,7 @@ def test_mini_dryrun_8dev_train_and_decode():
         results = {}
         for mesh_shape, axes in [((2, 4), ("data", "model")),
                                  ((2, 2, 2), ("pod", "data", "model"))]:
-            mesh = jax.make_mesh(mesh_shape, axes)
+            mesh = make_mesh(mesh_shape, axes)
             mod = get_arch("tinyllama-1.1b")
             api = mod.api(mod.config(reduced=True))
             # shrink the assigned shapes to reduced scale
@@ -86,7 +89,7 @@ def test_mini_dryrun_8dev_train_and_decode():
             }
             for shape in ("train_4k", "decode_32k"):
                 fn, args = lowerables(api, shape, mesh)
-                with mesh:
+                with jax.set_mesh(mesh):
                     compiled = jax.jit(fn).lower(*args).compile()
                 a = analyze_compiled(compiled, total_devices=mesh.size)
                 results[f"{len(mesh_shape)}d-{shape}"] = {

@@ -8,6 +8,7 @@ import pytest
 from repro.core import EmbeddingSpec
 from repro.data.criteo import CriteoSpec, batch_at
 from repro.dist.compress import ef_psum_grads, init_error_state, quantize_int8
+from repro.launch.mesh import make_mesh
 from repro.models.dlrm import DLRMConfig, dlrm_init, dlrm_loss_fn
 from repro.optim.optimizers import adam, adagrad, rowwise_adagrad, partitioned
 from repro.train.loop import (SimulatedFailure, TrainConfig, Trainer,
@@ -123,11 +124,11 @@ def test_error_feedback_is_unbiased_over_time(mode):
 def test_dp_shard_map_compressed_training_runs():
     """shard_map DP path with bf16-compressed reduction on a 1-device mesh."""
     from repro.train.loop import init_dp_state, make_dp_train_step
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     opt = adagrad(1e-2)
     state = init_dp_state(dlrm_init(jax.random.PRNGKey(0), CFG), opt)
     step = jax.jit(make_dp_train_step(_loss_fn, opt, mesh, compress="bf16"))
-    with mesh:
+    with jax.set_mesh(mesh):
         for i in range(3):
             state, m = step(state, batch_at(0, i, 32, SPEC))
     assert np.isfinite(float(m["loss"]))
