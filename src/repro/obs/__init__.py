@@ -5,9 +5,8 @@ Three pieces, all zero-dependency and off by default:
 * ``MetricsRegistry`` (``registry``) — labeled ``Counter`` / ``Gauge`` /
   ``Histogram`` with snapshot/JSONL sinks and multi-engine merge;
 * ``Tracer`` (``trace``) — ``span()`` context managers and caller-timed
-  ``complete()`` events exporting Chrome-trace/Perfetto JSON, with
-  optional ``jax.block_until_ready`` fencing and a ``jax.profiler``
-  annotation bridge;
+  ``complete()`` events exporting Chrome-trace/Perfetto JSON, and an
+  ``anchor()`` that joins them to a ``jax.profiler`` trace's clock;
 * ``CollisionTelemetry`` (``collision``) — measured collision mass over
   served ids, the planner's predicted-vs-observed feedback signal.
 
@@ -39,12 +38,9 @@ class Obs:
     engine calls ``attach_collisions(table_sizes)`` when it boots).
     """
 
-    def __init__(self, *, trace: bool = False, collisions: bool = False,
-                 fence: bool = False, jax_annotations: bool = False):
+    def __init__(self, *, trace: bool = False, collisions: bool = False):
         self.registry = MetricsRegistry()
-        self.tracer: Optional[Tracer] = (
-            Tracer(fence=fence, jax_annotations=jax_annotations)
-            if trace else None)
+        self.tracer: Optional[Tracer] = Tracer() if trace else None
         self.want_collisions = collisions
         self.collisions: Optional[CollisionTelemetry] = None
 

@@ -2,29 +2,29 @@
 
 ``Tracer`` records *complete* events (name, begin, duration) on a
 monotonic clock, either through the ``span("stage")`` context manager
-(nesting tracked per thread — the train loop's usage) or through
-``complete(name, t0, dur)`` when the caller already owns the boundary
-timestamps (the serving engine's usage: its stage timers double as the
-trace events, so tracing adds zero extra clock reads).
+(nesting tracked per thread) or through ``complete(name, t0, dur)`` when
+the caller already owns the boundary timestamps (the serving engine's
+usage: its stage timers double as the trace events, so tracing adds no
+clock read per stage).
+
+Every event carries a Chrome-trace ``cat``:
+
+* ``"host"`` — an interval in which the recording thread was doing this
+  work (the engine's ``serve.*`` stages, the trainer's ``train.*`` spans);
+* ``"interval"`` — a span that overlaps other work (a whole serving
+  ``wave``, a request's ``queue_wait``, a wave ``inflight`` on the device).
 
 Export is the Chrome Trace Event JSON format (``{"traceEvents": [...]}``
 with ``ph: "X"`` complete events, microsecond timestamps), which
-``chrome://tracing`` and https://ui.perfetto.dev both load directly —
-one wave renders as a ``wave`` bar with its stage bars nested inside.
+``chrome://tracing`` and https://ui.perfetto.dev both load directly.
 
-Two honesty knobs:
-
-* ``fence=True`` — ``tracer.fence(x)`` calls ``jax.block_until_ready``
-  on ``x`` before the enclosing span closes, so a span around an async
-  dispatch measures *device* time, not dispatch time.  Off by default:
-  fencing serializes the pipeline and is a measurement mode, never a
-  serving mode (with ``fence=False``, ``fence(x)`` is a no-op
-  passthrough and dispatch stays fully async).
-* ``jax_annotations=True`` — each ``span`` additionally enters a
-  ``jax.profiler.TraceAnnotation``, so when a run is wrapped in
-  ``jax.profiler.trace`` the engine's logical stages line up against
-  XLA's own timeline.  Guarded import: without jax (or an old profiler
-  API) the flag degrades to plain spans.
+**Joining a profiler trace.**  The events are on the tracer's own clock,
+which is not the clock of a ``jax.profiler`` trace.  ``anchor()``, called
+while the profiler runs, enters a ``TraceAnnotation`` named
+``ANCHOR`` and records an ``ANCHOR`` event at a clock read made inside it;
+the offset between the two timestamps of that one moment maps every event
+onto the profiler's timeline (both clocks run at the same rate, so one
+anchor is enough).
 
 The tracer is append-only and bounded (``max_events``, oldest dropped);
 ``drain()`` hands the events over and clears, so a long-running engine
@@ -37,42 +37,32 @@ import contextlib
 import json
 import threading
 import time
-from typing import Optional
 
-__all__ = ["Tracer"]
+__all__ = ["Tracer", "ANCHOR"]
+
+ANCHOR = "obs.clock_anchor"
 
 
 class Tracer:
-    def __init__(self, *, fence: bool = False, jax_annotations: bool = False,
-                 max_events: int = 200_000, pid: int = 0):
-        self.fence_enabled = fence
+    def __init__(self, *, max_events: int = 200_000, pid: int = 0):
         self.max_events = max_events
         self.pid = pid
         self.events: list[dict] = []
         self._lock = threading.Lock()
         self._local = threading.local()
         self._t0 = time.monotonic()
-        self._annotate = None
-        if jax_annotations:
-            try:
-                from jax.profiler import TraceAnnotation
-                self._annotate = TraceAnnotation
-            except Exception:  # jax absent or profiler API drifted
-                self._annotate = None
 
     # ------------------------------------------------------------- recording
-
-    def _now(self) -> float:
-        return time.monotonic()
 
     def _depth(self) -> int:
         return getattr(self._local, "depth", 0)
 
-    def complete(self, name: str, t0: float, dur_s: float, **args) -> None:
+    def complete(self, name: str, t0: float, dur_s: float, *,
+                 cat: str = "host", **args) -> None:
         """Record one complete event from caller-owned monotonic
         timestamps (``t0`` from ``time.monotonic()``, duration in
         seconds).  The hot-path entry point: no clock reads here."""
-        ev = {"name": name, "ph": "X", "pid": self.pid,
+        ev = {"name": name, "cat": cat, "ph": "X", "pid": self.pid,
               "tid": threading.get_ident() & 0xFFFF,
               "ts": (t0 - self._t0) * 1e6, "dur": dur_s * 1e6}
         if args:
@@ -82,45 +72,30 @@ class Tracer:
             if len(self.events) > self.max_events:
                 del self.events[0]
 
-    def instant(self, name: str, **args) -> None:
-        ev = {"name": name, "ph": "i", "pid": self.pid,
-              "tid": threading.get_ident() & 0xFFFF,
-              "ts": (self._now() - self._t0) * 1e6, "s": "t"}
-        if args:
-            ev["args"] = args
-        with self._lock:
-            self.events.append(ev)
-            if len(self.events) > self.max_events:
-                del self.events[0]
-
     @contextlib.contextmanager
     def span(self, name: str, **args):
-        """Context manager form: times the block, tracks nesting depth
-        per thread (depth rides in ``args.depth`` so malformed nesting is
-        assertable), optionally mirrors into a jax profiler annotation."""
+        """Context manager form: times the block as a ``host`` event and
+        tracks nesting depth per thread (depth rides in ``args.depth`` so
+        malformed nesting is assertable)."""
         depth = self._depth()
         self._local.depth = depth + 1
-        ctx = self._annotate(name) if self._annotate is not None else None
-        if ctx is not None:
-            ctx.__enter__()
-        t0 = self._now()
+        t0 = time.monotonic()
         try:
             yield self
         finally:
-            dur = self._now() - t0
-            if ctx is not None:
-                ctx.__exit__(None, None, None)
+            dur = time.monotonic() - t0
             self._local.depth = depth
             self.complete(name, t0, dur, depth=depth, **args)
 
-    def fence(self, value):
-        """Block on ``value`` (``jax.block_until_ready``) when fencing is
-        enabled, so the enclosing span measures device completion, not
-        async dispatch.  Passthrough when disabled."""
-        if self.fence_enabled and value is not None:
-            import jax
-            jax.block_until_ready(value)
-        return value
+    def anchor(self) -> None:
+        """Mark one moment on both clocks: a ``jax.profiler``
+        ``TraceAnnotation`` named ``ANCHOR`` in the running profiler
+        trace, and an ``ANCHOR`` event of zero length at a clock read
+        made inside it.  Call it while the profiler runs."""
+        from jax.profiler import TraceAnnotation
+        with TraceAnnotation(ANCHOR):
+            t = time.monotonic()
+        self.complete(ANCHOR, t, 0.0, cat="interval")
 
     # ------------------------------------------------------------- export
 

@@ -86,6 +86,16 @@ class RecRequest:
 # and ride along as extra, non-partition stages
 STAGE_PARTITION = ("probe", "dense", "inflight", "miss_gather", "flush")
 STAGES = ("queue_wait", "pad") + STAGE_PARTITION
+# the tracer's host spans of one wave, in order (``cat`` "host": the
+# engine's thread doing this wave's work); ``probe`` is split into the
+# host-to-device transfer of the in-graph embed's ids and masks
+# (``serve.h2d``, empty on the cache paths) and the rest of the embed
+# dispatch (``serve.embed``), and the wave's own forming and score
+# writing are spans of their own.  ``wave``, ``queue_wait`` and
+# ``inflight`` are ``cat`` "interval" events: they overlap other work.
+HOST_SPANS = ("serve.form_wave", "serve.pad", "serve.h2d", "serve.embed",
+              "serve.dense", "serve.miss_gather", "serve.flush",
+              "serve.scores")
 
 
 def _next_pow2(n: int) -> int:
@@ -822,7 +832,10 @@ class RecsysEngine:
 
     # ------------------------------------------------------------- execution
 
-    def _dispatch(self, wave: list[RecRequest]) -> None:
+    def _dispatch(self, wave: list[RecRequest],
+                  tf: Optional[float] = None) -> None:
+        """``tf``: when the tracer is on, the monotonic time the wave
+        began to form (the start of its ``serve.form_wave`` span)."""
         obs = self._obs
         tq = time.monotonic() if obs is not None else None
         dense, idx, mask = self._pad_wave(wave)
@@ -832,6 +845,7 @@ class RecsysEngine:
             # are this wave's own buffers, so holding references is safe
             obs.collisions.record(idx, mask, live_rows=len(wave))
         check = None
+        th = t0
         if self._n_shards > 1:
             logits, check, ta, path = self._dispatch_sharded(dense, idx,
                                                              mask)
@@ -850,8 +864,14 @@ class RecsysEngine:
                 feats = jnp.asarray(self._embed_cached(idx, mask))
                 path = "host_cache"
             else:
-                feats = self._embed_fwd(self.params, jnp.asarray(idx),
-                                        jnp.asarray(mask))
+                # moving the ids and masks is a span of its own when traced
+                # (the cache paths move them inside the embed); the device
+                # copies are let go once the embed holds them
+                idx_d, mask_d = jnp.asarray(idx), jnp.asarray(mask)
+                if tf is not None:
+                    th = time.monotonic()
+                feats = self._embed_fwd(self.params, idx_d, mask_d)
+                del idx_d, mask_d
                 path = "in_graph"
             ta = time.monotonic() if obs is not None else None
             logits = self._dense_fwd(self.params, jnp.asarray(dense), feats)
@@ -860,7 +880,8 @@ class RecsysEngine:
         if obs is not None:
             tb = time.monotonic()
             waits = [tq - r.t_submit for r in wave if r.t_submit is not None]
-            oi = {"tq": tq, "t0": t0, "ta": ta, "tb": tb,
+            oi = {"tf": tf, "tq": tq, "t0": t0, "th": th, "ta": ta,
+                  "tb": tb,
                   "queue_wait": max(waits) if waits else 0.0,
                   "n": len(wave), "bb": idx.shape[0], "lb": idx.shape[2]}
             if self._c_wire is not None:
@@ -908,12 +929,13 @@ class RecsysEngine:
         self.wave_latencies_s.append(t1 - t0)
         self.wave_sizes.append(len(wave))
         self.wave_paths[path] += 1
-        if oi is not None:
-            self._record_wave(oi, tc, td, t1)
         for b, r in enumerate(wave):  # padded rows beyond len(wave) discarded
             r.score = float(logits[b])
             r.done = True
             self.completed[r.uid] = r
+        if oi is not None:
+            ts = time.monotonic() if oi["tf"] is not None else None
+            self._record_wave(oi, tc, td, t1, ts)
         return wave
 
     def step(self) -> list[RecRequest]:
@@ -922,9 +944,12 @@ class RecsysEngine:
         continuous mode lets up to ``max_inflight`` waves ride JAX async
         dispatch and only blocks on the oldest beyond that (or drains when
         the queue is empty)."""
+        obs = self._obs
+        tf = time.monotonic() \
+            if obs is not None and obs.tracer is not None else None
         wave = self._form_wave()
         if wave:
-            self._dispatch(wave)
+            self._dispatch(wave, tf)
         limit = 0 if self.batching == "waves" else self.max_inflight
         done: list[RecRequest] = []
         while self._inflight and (len(self._inflight) > limit
@@ -939,13 +964,16 @@ class RecsysEngine:
 
     # ------------------------------------------------------------- metrics
 
-    def _record_wave(self, oi: dict, tc: float, td: float, t1: float) -> None:
+    def _record_wave(self, oi: dict, tc: float, td: float, t1: float,
+                     ts: Optional[float]) -> None:
         """Fold one reaped wave's boundary timestamps into the registry
         (and tracer).  The five partition stages tile [t0, t1] exactly:
         probe (embed/cache-probe dispatch), dense (dense dispatch),
         inflight (async pipeline gap until reap), miss_gather (settling
         the speculative probe — recompute on miss, accounting on hit),
-        flush (the block_until_ready sync)."""
+        flush (the block_until_ready sync).  With the tracer on (``ts``,
+        the end of score writing, is then set) the wave's ``HOST_SPANS``
+        tile [form start, ts] but for its time in flight."""
         obs = self._obs
         t0, ta, tb = oi["t0"], oi["ta"], oi["tb"]
         stages = (("queue_wait", oi["tq"] - oi["queue_wait"],
@@ -961,12 +989,17 @@ class RecsysEngine:
         self._h_wave.observe(t1 - t0)
         self._c_req.inc(oi["n"])
         self._c_waves.inc()
-        if obs.tracer is not None:
-            tr = obs.tracer
-            tr.complete("wave", t0, t1 - t0, requests=oi["n"],
-                        batch=oi["bb"], bag=oi["lb"])
-            for name, ts, dur in stages:
-                tr.complete(name, ts, dur)
+        tr = obs.tracer
+        if tr is not None and ts is not None:
+            tr.complete("wave", t0, t1 - t0, cat="interval",
+                        requests=oi["n"], batch=oi["bb"], bag=oi["lb"])
+            tr.complete("queue_wait", oi["tq"] - oi["queue_wait"],
+                        oi["queue_wait"], cat="interval")
+            tr.complete("inflight", tb, tc - tb, cat="interval")
+            bounds = ((oi["tf"], oi["tq"]), (oi["tq"], t0), (t0, oi["th"]),
+                      (oi["th"], ta), (ta, tb), (tc, td), (td, t1), (t1, ts))
+            for name, (start, end) in zip(HOST_SPANS, bounds):
+                tr.complete(name, start, end - start)
 
     def stage_summary(self) -> dict:
         """Per-stage latency summaries plus the partition check the obs

@@ -74,10 +74,18 @@ def make_train_step(loss_fn, optimizer: Optimizer, *, clip_norm=None,
     assigned train_4k batch of 256 sequences).  Gradients accumulate in
     f32; loss/metrics are microbatch means, bitwise independent of accum
     for linear losses.
+
+    The loss runs under ``jax.named_scope("forward")`` and the optimizer
+    under ``"optimizer"``: HLO metadata only, so a profile can split the
+    step's device time (backward ops carry ``transpose(jvp(forward))``).
     """
 
+    def _forward(params, batch):
+        with jax.named_scope("forward"):
+            return loss_fn(params, batch)
+
     def _grads(params, batch):
-        return jax.value_and_grad(loss_fn, has_aux=True)(params, batch)
+        return jax.value_and_grad(_forward, has_aux=True)(params, batch)
 
     def step(state, batch):
         if accum == 1:
@@ -109,8 +117,9 @@ def make_train_step(loss_fn, optimizer: Optimizer, *, clip_norm=None,
         if clip_norm is not None:
             grads, gnorm = clip_by_global_norm(grads, clip_norm)
             metrics = dict(metrics, grad_norm=gnorm)
-        new_params, new_opt = optimizer.update(grads, state["opt"],
-                                               state["params"], state["step"])
+        with jax.named_scope("optimizer"):
+            new_params, new_opt = optimizer.update(
+                grads, state["opt"], state["params"], state["step"])
         new_state = {"params": new_params, "opt": new_opt, "step": state["step"] + 1}
         return new_state, dict(metrics, loss=loss)
 
@@ -353,9 +362,9 @@ def init_fsdp_state(params, optimizer: Optimizer, mesh, *, policy="auto",
 class Trainer:
     def __init__(self, train_step, cfg: TrainConfig, *,
                  batch_at: Callable[[int], Any], obs=None, step_wire=None):
-        """``obs`` (an ``repro.obs.Obs``) turns on per-step spans and
-        counters; ``step_wire`` is an accounted wire-byte report for one
-        step (``dist.accounting.grad_wire_bytes`` /
+        """``obs`` (an ``repro.obs.Obs``) turns on per-step spans (see
+        ``step``) and counters; ``step_wire`` is an accounted wire-byte
+        report for one step (``dist.accounting.grad_wire_bytes`` /
         ``dp_step_wire_bytes`` / ``fsdp_step_wire_bytes`` output) — its
         per-leaf entries become per-leaf wire counters incremented every
         step, so the registry shows what the collectives actually carry.
@@ -400,18 +409,41 @@ class Trainer:
                 return restored
         return state
 
+    def step(self, state, batch):
+        """One step of the jitted ``train_step``, waiting for its loss.
+        With the tracer on it records two ``host`` spans: ``train.dispatch``
+        (the jitted call until it returns: argument handling, output
+        allocation, launch) and ``train.wait`` (blocking on the loss)."""
+        tr = self._obs.tracer if self._obs is not None else None
+        if tr is None:
+            state, metrics = self.train_step(state, batch)
+            jax.block_until_ready(metrics["loss"])
+            return state, metrics
+        t0 = time.monotonic()
+        state, metrics = self.train_step(state, batch)
+        t1 = time.monotonic()
+        jax.block_until_ready(metrics["loss"])
+        t2 = time.monotonic()
+        tr.complete("train.dispatch", t0, t1 - t0)
+        tr.complete("train.wait", t1, t2 - t1)
+        return state, metrics
+
     def run(self, state, *, fail_at_step: Optional[int] = None):
         cfg = self.cfg
         history = []
         durations = self.step_seconds
         start = int(state["step"])
+        tr = self._obs.tracer if self._obs is not None else None
         for step in range(start, cfg.num_steps):
             if fail_at_step is not None and step == fail_at_step:
                 raise SimulatedFailure(f"injected failure at step {step}")
-            batch = self.batch_at(step)
+            if tr is None:
+                batch = self.batch_at(step)
+            else:
+                with tr.span("train.batch"):
+                    batch = self.batch_at(step)
             t0 = time.monotonic()
-            state, metrics = self.train_step(state, batch)
-            jax.block_until_ready(metrics["loss"])
+            state, metrics = self.step(state, batch)
             dt = time.monotonic() - t0
             straggled = False
             if len(durations) >= 5:
@@ -427,8 +459,9 @@ class Trainer:
                     self._c_strag.inc()
                 for h, b in self._wire_handles:
                     h.inc(b)
-                if self._obs.tracer is not None:
-                    self._obs.tracer.complete("train_step", t0, dt, step=step)
+                if tr is not None:
+                    tr.complete("train_step", t0, dt, cat="interval",
+                                step=step)
             if step % cfg.log_every == 0 or step == cfg.num_steps - 1:
                 history.append((step, float(metrics["loss"])))
             if self.checkpointer and (step + 1) % cfg.ckpt_every == 0:

@@ -3,6 +3,7 @@ stage timelines, collision telemetry, and the read-only contract
 (obs-on must not change a single score)."""
 
 import json
+import time
 
 import jax
 import numpy as np
@@ -17,7 +18,9 @@ from repro.optim.optimizers import adagrad
 from repro.plan.freq import FeatureStats
 from repro.serve.cache import HotRowCache
 from repro.serve.quantize import quantize_params
-from repro.serve.recsys import STAGE_PARTITION, STAGES, RecsysEngine
+from repro.serve import recsys
+from repro.serve.recsys import (HOST_SPANS, STAGE_PARTITION, STAGES,
+                                RecsysEngine)
 from repro.train.loop import TrainConfig, Trainer, init_state, make_train_step
 
 SIZES = (100, 500, 33)
@@ -158,7 +161,7 @@ def test_tracer_nesting_and_chrome_trace_round_trip():
     with tr.span("outer", kind="t"):
         with tr.span("inner"):
             pass
-    tr.instant("mark")
+    tr.complete("mark", tr._t0, 0.0, cat="interval", k=1)
     payload = json.loads(tr.to_json())
     evs = payload["traceEvents"]
     by_name = {e["name"]: e for e in evs}
@@ -166,21 +169,17 @@ def test_tracer_nesting_and_chrome_trace_round_trip():
     assert [e["name"] for e in evs] == ["inner", "outer", "mark"]
     assert by_name["outer"]["args"]["depth"] == 0
     assert by_name["inner"]["args"]["depth"] == 1
+    assert by_name["mark"]["args"] == {"k": 1}
+    assert [e["cat"] for e in evs] == ["host", "host", "interval"]
     for e in evs:
-        assert e["ph"] in ("X", "i") and e["ts"] >= 0
+        assert e["ph"] == "X" and e["ts"] >= 0 and e["dur"] >= 0
     # inner nests inside outer on the chrome timeline
     o, i = by_name["outer"], by_name["inner"]
     assert o["ts"] <= i["ts"]
     assert i["ts"] + i["dur"] <= o["ts"] + o["dur"] + 1e-3
     assert len(tr) == 3
     assert len(tr.drain()) == 3 and len(tr) == 0
-
-
-def test_tracer_fence_passthrough_and_bound():
-    x = jax.numpy.ones(3)
-    assert Tracer().fence(x) is x                 # disabled: no-op
-    assert Tracer(fence=True).fence(x) is x       # enabled: blocks, returns
-    tr = Tracer(max_events=2)
+    tr = Tracer(max_events=2)                     # bounded: oldest dropped
     for k in range(5):
         tr.complete(f"e{k}", 0.0, 0.0)
     assert [e["name"] for e in tr.drain()] == ["e3", "e4"]
@@ -262,10 +261,11 @@ def test_engine_stage_partition_sums_to_latency():
         assert all(ss[s]["count"] == waves for s in STAGE_PARTITION)
         assert obs.registry.counter("serve_requests_total").value() \
             == len(reqs)
-        # one wave bar + one bar per partition stage per wave
+        # one wave bar, its queue wait and time in flight, and the eight
+        # host spans per wave
         names = [e["name"] for e in obs.tracer.events]
         assert names.count("wave") == waves
-        for s in STAGE_PARTITION:
+        for s in ("queue_wait", "inflight") + HOST_SPANS:
             assert names.count(s) == waves
         assert obs.collisions is not None and obs.collisions.waves == waves
 
@@ -305,6 +305,67 @@ def test_engine_obs_is_read_only_bitwise():
     done_off, done_on = eng_off.run_until_drained(), eng_on.run_until_drained()
     for a, b in uids:
         assert done_on[b].score == done_off[a].score
+
+
+class _Clock:
+    """``time`` for the engine module, counting ``monotonic`` reads."""
+
+    def __init__(self):
+        self.reads = 0
+
+    def monotonic(self):
+        self.reads += 1
+        return time.monotonic()
+
+
+@pytest.mark.parametrize("mode", ["off", "registry", "traced"])
+def test_engine_host_spans_per_wave_and_clock_reads(mode, monkeypatch):
+    """With the tracer on, each wave records the eight ``serve.*`` host
+    spans, in order and back to back but for the wave's time in flight,
+    at three clock reads a wave more than the registry alone; off, the
+    engine records nothing and reads the clock twice a wave (its own
+    latency), as before spans existed."""
+    cfg = _cfg()
+    qp = quantize_params(dlrm_init(jax.random.PRNGKey(0), cfg))
+    obs = {"off": None, "registry": Obs(),
+           "traced": Obs(trace=True)}[mode]
+    eng = RecsysEngine(cfg, qp, max_batch=4, obs=obs)
+    reqs = _requests(4, seed=11)
+    for d, b in reqs:                       # compile the bucket first
+        eng.submit(d, b)
+    eng.run_until_drained()
+    if obs is not None and obs.tracer is not None:
+        obs.tracer.drain()
+    clock = _Clock()
+    monkeypatch.setattr(recsys, "time", clock)
+    for d, b in reqs:
+        eng.submit(d, b)
+    submits, clock.reads = clock.reads, 0
+    steps = 0
+    while eng._queue or eng._inflight:
+        eng.step()
+        steps += 1
+    assert steps == 1                       # one wave of four
+    per_wave = {"off": 2, "registry": 7, "traced": 10}[mode]
+    assert clock.reads == per_wave
+    assert submits == (0 if obs is None else len(reqs))
+    if mode != "traced":
+        assert obs is None or obs.tracer is None
+        return
+    evs = obs.tracer.drain()
+    host = [e for e in evs if e["cat"] == "host"]
+    assert [e["name"] for e in host] == list(HOST_SPANS)
+    assert {e["name"] for e in evs if e["cat"] == "interval"} \
+        == {"wave", "queue_wait", "inflight"}
+    assert all(e["dur"] >= 0 for e in evs)
+    ends = {e["name"]: e["ts"] + e["dur"] for e in evs}
+    starts = {e["name"]: e["ts"] for e in evs}
+    for a, b in zip(HOST_SPANS, HOST_SPANS[1:]):
+        gap = starts[b] - ends[a]
+        if a == "serve.dense":              # the wave in flight between
+            assert gap == pytest.approx(ends["inflight"] - starts["inflight"])
+        else:
+            assert gap == pytest.approx(0.0, abs=1e-6)
 
 
 def test_reset_metrics_resets_cache_counters_keeps_residency():
@@ -370,3 +431,35 @@ def test_trainer_obs_counters_and_wire_handles():
     assert wire.value(leaf="_other", mode="aggregate") == 6 * 77.0
     steps = [e for e in obs.tracer.events if e["name"] == "train_step"]
     assert [e["args"]["step"] for e in steps] == list(range(6))
+
+
+def test_trainer_step_records_dispatch_and_wait_bitwise():
+    """``Trainer.step`` with the tracer on records ``train.dispatch`` then
+    ``train.wait`` per step, and returns bitwise the state and loss that
+    the bare jitted ``train_step`` gives; ``run`` adds ``train.batch``."""
+    spec = CriteoSpec(table_sizes=SIZES)
+    cfg = _cfg()
+    opt = adagrad(1e-2)
+    state0 = init_state(dlrm_init(jax.random.PRNGKey(0), cfg), opt)
+    batches = [batch_at(0, s, 16, spec) for s in range(3)]
+    step = make_train_step(lambda p, b: dlrm_loss_fn(p, b, cfg), opt)
+    obs = Obs(trace=True)
+    on = Trainer(step, TrainConfig(num_steps=3), batch_at=batches.__getitem__,
+                 obs=obs)
+    off = Trainer(step, TrainConfig(num_steps=3), batch_at=batches.__getitem__)
+    a = b = state0
+    for t in range(3):
+        a, ma = on.step(a, batches[t])
+        b, mb = off.train_step(b, batches[t])
+        assert float(ma["loss"]) == float(mb["loss"])
+    for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+    evs = obs.tracer.drain()
+    assert [e["name"] for e in evs] == ["train.dispatch", "train.wait"] * 3
+    assert all(e["cat"] == "host" and e["dur"] >= 0 for e in evs)
+    for d, w in zip(evs[::2], evs[1::2]):   # the wait follows the dispatch
+        assert w["ts"] >= d["ts"] + d["dur"] - 1e-6
+    on.run(state0)
+    names = [e["name"] for e in obs.tracer.drain()]
+    assert names == ["train.batch", "train.dispatch", "train.wait",
+                     "train_step"] * 3
