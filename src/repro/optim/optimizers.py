@@ -140,11 +140,10 @@ def adam(lr=1e-3, b1: float = 0.9, b2: float = 0.999, eps: float = 1e-8,
     sched = _sched(lr)
 
     def init_leaf(p):
-        z = jnp.zeros(p.shape, jnp.float32)
-        s = {"m": z, "v": z}
-        if amsgrad:
-            s["vmax"] = z
-        return s
+        # one buffer per entry: a jit that donates the state cannot take
+        # the same buffer twice
+        names = ("m", "v", "vmax") if amsgrad else ("m", "v")
+        return {k: jnp.zeros(p.shape, jnp.float32) for k in names}
 
     def update_leaf(g, s, p, step):
         t = step + 1

@@ -59,8 +59,15 @@ class TrainConfig:
     watchdog_factor: float = 3.0
 
 
+# one program for the whole tree (an eager copy compiles one per shape)
+_copy_tree = jax.jit(lambda tree: jax.tree.map(jnp.copy, tree))
+
+
 def init_state(params, optimizer: Optimizer):
-    return {"params": params, "opt": optimizer.init(params),
+    """The train state, its params copied once into buffers of their own:
+    a ``Trainer`` consumes the state it is handed, and the caller's
+    ``params`` stay readable."""
+    return {"params": _copy_tree(params), "opt": optimizer.init(params),
             "step": jnp.zeros((), jnp.int32)}
 
 
@@ -362,14 +369,24 @@ def init_fsdp_state(params, optimizer: Optimizer, mesh, *, policy="auto",
 class Trainer:
     def __init__(self, train_step, cfg: TrainConfig, *,
                  batch_at: Callable[[int], Any], obs=None, step_wire=None):
-        """``obs`` (an ``repro.obs.Obs``) turns on per-step spans (see
-        ``step``) and counters; ``step_wire`` is an accounted wire-byte
-        report for one step (``dist.accounting.grad_wire_bytes`` /
-        ``dp_step_wire_bytes`` / ``fsdp_step_wire_bytes`` output) — its
-        per-leaf entries become per-leaf wire counters incremented every
-        step, so the registry shows what the collectives actually carry.
-        Both default off; the obs-off loop is unchanged."""
-        self.train_step = jax.jit(train_step)
+        """``train_step`` is jitted with the state donated: the step writes
+        the new params and optimizer state into the buffers of the state
+        passed in, so a state handed to ``train_step``, ``step`` or
+        ``run`` is consumed (its arrays are deleted) and only the state
+        returned may be used. Every leaf of the state must be a buffer of
+        its own (``init_state`` makes it so).
+
+        ``obs`` (an ``repro.obs.Obs``) turns on per-step spans (see
+        ``step``), counters, and the gauge ``train_state_aliased_share``
+        (set on the first step: the share of the compiled step's output
+        bytes written into donated input buffers); ``step_wire`` is an
+        accounted wire-byte report for one step
+        (``dist.accounting.grad_wire_bytes`` / ``dp_step_wire_bytes`` /
+        ``fsdp_step_wire_bytes`` output) — its per-leaf entries become
+        per-leaf wire counters incremented every step, so the registry
+        shows what the collectives actually carry. Both default off; the
+        obs-off loop is unchanged."""
+        self.train_step = jax.jit(train_step, donate_argnums=0)
         self.cfg = cfg
         self.batch_at = batch_at
         self.checkpointer = (ckpt.AsyncCheckpointer(cfg.ckpt_dir, keep=cfg.keep)
@@ -377,6 +394,7 @@ class Trainer:
         self.straggler_events: list[tuple[int, float]] = []
         self.step_seconds: list[float] = []   # wall time of each run() step
         self._obs = obs
+        self._aliasing_read = False
         if obs is not None:
             self._h_step = obs.histogram(
                 "train_step_seconds", "per-step wall time").labels()
@@ -412,9 +430,12 @@ class Trainer:
     def step(self, state, batch):
         """One step of the jitted ``train_step``, waiting for its loss.
         With the tracer on it records two ``host`` spans: ``train.dispatch``
-        (the jitted call until it returns: argument handling, output
-        allocation, launch) and ``train.wait`` (blocking on the loss)."""
-        tr = self._obs.tracer if self._obs is not None else None
+        (the jitted call until it returns: argument handling and launch)
+        and ``train.wait`` (blocking on the loss). ``state`` is consumed."""
+        obs = self._obs
+        if obs is not None and not self._aliasing_read:
+            self._read_aliasing(obs, state, batch)
+        tr = obs.tracer if obs is not None else None
         if tr is None:
             state, metrics = self.train_step(state, batch)
             jax.block_until_ready(metrics["loss"])
@@ -428,7 +449,19 @@ class Trainer:
         tr.complete("train.wait", t1, t2 - t1)
         return state, metrics
 
+    def _read_aliasing(self, obs, state, batch):
+        """Set ``train_state_aliased_share`` from the compiled step (the
+        one the call that follows runs, from the same cache)."""
+        mem = self.train_step.lower(state, batch).compile().memory_analysis()
+        obs.gauge("train_state_aliased_share",
+                  "share of the step's output bytes written into donated "
+                  "state buffers").set(
+            mem.alias_size_in_bytes / mem.output_size_in_bytes)
+        self._aliasing_read = True
+
     def run(self, state, *, fail_at_step: Optional[int] = None):
+        """Steps from ``state["step"]`` to ``cfg.num_steps``; ``state`` is
+        consumed, the final state returned."""
         cfg = self.cfg
         history = []
         durations = self.step_seconds

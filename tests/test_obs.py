@@ -440,14 +440,17 @@ def test_trainer_step_records_dispatch_and_wait_bitwise():
     spec = CriteoSpec(table_sizes=SIZES)
     cfg = _cfg()
     opt = adagrad(1e-2)
-    state0 = init_state(dlrm_init(jax.random.PRNGKey(0), cfg), opt)
+
+    def state0():   # a Trainer consumes its state: a fresh one each use
+        return init_state(dlrm_init(jax.random.PRNGKey(0), cfg), opt)
+
     batches = [batch_at(0, s, 16, spec) for s in range(3)]
     step = make_train_step(lambda p, b: dlrm_loss_fn(p, b, cfg), opt)
     obs = Obs(trace=True)
     on = Trainer(step, TrainConfig(num_steps=3), batch_at=batches.__getitem__,
                  obs=obs)
     off = Trainer(step, TrainConfig(num_steps=3), batch_at=batches.__getitem__)
-    a = b = state0
+    a, b = state0(), state0()
     for t in range(3):
         a, ma = on.step(a, batches[t])
         b, mb = off.train_step(b, batches[t])
@@ -459,7 +462,7 @@ def test_trainer_step_records_dispatch_and_wait_bitwise():
     assert all(e["cat"] == "host" and e["dur"] >= 0 for e in evs)
     for d, w in zip(evs[::2], evs[1::2]):   # the wait follows the dispatch
         assert w["ts"] >= d["ts"] + d["dur"] - 1e-6
-    on.run(state0)
+    on.run(state0())
     names = [e["name"] for e in obs.tracer.drain()]
     assert names == ["train.batch", "train.dispatch", "train.wait",
                      "train_step"] * 3

@@ -30,25 +30,28 @@ def _opt():
 
 def test_kill_restart_bitwise_determinism(tmp_path):
     opt = _opt()
-    state0 = init_state(dlrm_init(jax.random.PRNGKey(1), CFG), opt)
+
+    def state0():   # a Trainer consumes its state: a fresh one each use
+        return init_state(dlrm_init(jax.random.PRNGKey(1), CFG), opt)
+
     tc = TrainConfig(num_steps=20, ckpt_every=10, ckpt_dir=str(tmp_path), log_every=5)
     batcher = lambda s: batch_at(0, s, 64, SPEC)
 
     tr = Trainer(make_train_step(_loss_fn, opt), tc, batch_at=batcher)
     with pytest.raises(SimulatedFailure):
-        tr.run(state0, fail_at_step=15)
+        tr.run(state0(), fail_at_step=15)
     # the step-10 checkpoint was issued 5 steps before the crash; let the
     # async writer finish (in real time-scales it completed long before)
     tr.checkpointer.wait()
 
     tr2 = Trainer(make_train_step(_loss_fn, opt), tc, batch_at=batcher)
-    resumed = tr2.resume_or(state0)
+    resumed = tr2.resume_or(state0())
     assert int(resumed["step"]) == 10
     final_resumed, _ = tr2.run(resumed)
 
     tr3 = Trainer(make_train_step(_loss_fn, opt),
                   TrainConfig(num_steps=20, ckpt_dir=None), batch_at=batcher)
-    final_direct, _ = tr3.run(state0)
+    final_direct, _ = tr3.run(state0())
     for a, b in zip(jax.tree.leaves(final_resumed["params"]),
                     jax.tree.leaves(final_direct["params"])):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
