@@ -42,6 +42,7 @@ class Run:
     cfg: dict
     mix: dict
     peaks: dict
+    devices: list = dataclasses.field(default_factory=list)
     setup_s: float = math.nan
     window_s: float = math.nan
     serve: Optional[dict] = None      # see serve_cell
@@ -120,22 +121,35 @@ def check_devices(chips: int) -> list:
 
 def program_config(cfg: dict):
     """The program's own config for this configuration, checked against
-    the sizes the configuration file states."""
+    the sizes the configuration file states: every key of its ``model``
+    that the program's config also has (lists as tuples), and the QR
+    embedding."""
     from repro.configs import get_arch
     mod = get_arch(cfg["program"]["arch"])
     pcfg = mod.config(reduced=cfg["program"]["reduced_sizes"])
     m = cfg["model"]
-    want = {"table_sizes": tuple(m["table_sizes"]), "emb_dim": m["emb_dim"],
-            "dense_dim": m["dense_dim"]}
-    want.update({k: tuple(m[k]) if isinstance(m[k], list) else m[k]
-                 for k in ("bottom_mlp", "top_mlp", "cross_layers", "deep_mlp")
-                 if k in m})
+    want = {k: tuple(v) if isinstance(v, list) else v for k, v in m.items()
+            if hasattr(pcfg, k)}
     got = {k: getattr(pcfg, k) for k in want}
     emb = pcfg.embedding
     if (got != want or emb.kind != "qr" or emb.op != m["op"]
             or emb.num_collisions != m["num_collisions"]):
         raise ValueError(f"program config {got} {emb} differs from {want}")
     return pcfg, mod.api(pcfg)
+
+
+def dp_compress(cell: dict, cfg: dict) -> Optional[str]:
+    """The gradient compression policy a training cell runs under: a cell
+    on several chips runs the program's data-parallel step, which needs
+    the configuration's ``train.compress``; a one-chip cell runs the plain
+    step, and a policy there would be ignored.  Either mismatch raises."""
+    compress = cfg["train"].get("compress")
+    if (cell["chips"] > 1) != (compress is not None):
+        raise ValueError(
+            f"cell {cell['name']!r} asks for {cell['chips']} chip(s) and its "
+            f"configuration states train.compress {compress!r}: a "
+            "data-parallel cell needs a policy, a one-chip cell none")
+    return compress
 
 
 def gap_ratio(prog: np.ndarray, ref: np.ndarray) -> float:
@@ -258,7 +272,7 @@ def serve_cell(run: Run, proc, seed: int, seconds: float, traced: bool,
                      gc_s_in_window=round(gcw.seconds, 4))
     if traced:
         serve_traced(run, engine, obs, proc, seed, keep_trace)
-    run.notes["memory_peak_bytes"] = memory_peak()
+    run.notes["memory_peak_bytes"] = memory_peak(run.devices)
 
     rng = np.random.default_rng([seed % (1 << 63), 7])
     rows = np.sort(rng.choice(len(reqs), min(SAMPLE, len(reqs)), replace=False))
@@ -296,23 +310,62 @@ def serve_traced(run: Run, engine, obs, proc, seed: int, keep_trace) -> None:
 
 # ---------------------------------------------------------------- training
 
+def train_program(run: Run, make_batch):
+    """``(trainer, start)``: the program's step in a ``Trainer`` (which
+    donates the state) driven by ``make_batch``, and ``start(params0) ->
+    (state, params0)``, its first state with ``params0`` placed as the
+    state's params are and left readable.  On one chip the plain step; on
+    several, as ``repro.launch.train`` builds it for ``--compress-policy``:
+    the data-parallel step over a ``data`` mesh of the cell's chips, the
+    state replicated, each batch split over ``data`` where it is made."""
+    import jax
+    import jax.numpy as jnp
+    from repro.train.loop import (TrainConfig, Trainer, init_dp_state,
+                                  init_state, make_dp_train_step,
+                                  make_train_step)
+    api = run.cfg["program_api"]
+    compress = dp_compress(run.cell, run.cfg)
+    if compress is None:
+        step = make_train_step(api.loss_fn, api.optimizer)
+
+        def start(params0):
+            return init_state(params0, api.optimizer), params0
+    else:
+        from jax.sharding import NamedSharding, PartitionSpec
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((len(run.devices),), ("data",), devices=run.devices)
+        rep = NamedSharding(mesh, PartitionSpec())
+        step = make_dp_train_step(api.loss_fn, api.optimizer, mesh,
+                                  compress=compress)
+        # the state's params are copies, so the donated state owns them
+        init = jax.jit(lambda p: init_dp_state(
+            jax.tree.map(jnp.copy, p), api.optimizer, compress=compress),
+            out_shardings=rep)
+
+        def start(params0):
+            params0 = jax.device_put(params0, rep)
+            return init(params0), params0
+        make_batch = jax.jit(make_batch, out_shardings=NamedSharding(
+            mesh, PartitionSpec("data")))
+    trainer = Trainer(step, TrainConfig(num_steps=0, log_every=1),
+                      batch_at=make_batch)
+    return trainer, start
+
+
 def train_cell(run: Run, proc, seed: int, seconds: float, traced: bool,
                counter, t_start: float, keep_trace: Optional[str]) -> None:
     import jax
     import jax.numpy as jnp
-    from repro.train.loop import TrainConfig, Trainer, init_state, make_train_step
     from .reference import api as ref
     from .reference.common import first_grad_norms
 
     cfg, mix, model, opt = run.cfg, run.mix, run.cfg["model"], run.cfg["train"]
-    api = cfg["program_api"]
     make_batch = proc.batch_fn(mix, model, seed)
     phases = Phases(t_start)
     params0 = jax.block_until_ready(ref.make_params(seed, model))
     phases.mark("weights")
-    state = init_state(params0, api.optimizer)
-    trainer = Trainer(make_train_step(api.loss_fn, api.optimizer),
-                      TrainConfig(num_steps=0, log_every=1), batch_at=make_batch)
+    trainer, start = train_program(run, make_batch)
+    state, params0 = start(params0)
     norms = jax.jit(lambda a, b: jnp.stack(
         [jnp.linalg.norm(x - y) for x, y in
          zip(jax.tree.leaves(a), jax.tree.leaves(b))]))
@@ -347,12 +400,17 @@ def train_cell(run: Run, proc, seed: int, seconds: float, traced: bool,
                      gc_s_in_window=round(gcw.seconds, 4))
     if traced:
         state, step = train_traced(run, trainer, state, step, keep_trace)
-    run.notes["memory_peak_bytes"] = memory_peak()
+    run.notes["memory_peak_bytes"] = memory_peak(run.devices)
+    batch_at = trainer.batch_at
     del state, met, trainer
     gc.collect()
 
-    want = ref.train_steps(ref.make_params(seed, model),
-                           [make_batch(t) for t in range(3)], model, opt)
+    # the batches the program stepped on, whole, on the reference's device
+    t_ref = time.monotonic()
+    batches = [jax.device_get(batch_at(t)) for t in range(3)]
+    want = ref.train_steps(ref.make_params(seed, model), batches, model, opt,
+                           replicas=run.cell["chips"])
+    run.notes["reference_s"] = round(time.monotonic() - t_ref, 3)
     got = {"losses": losses, "first_grad": first, "change1": change1,
            "change": change}
     numbers = train_numbers(got, want)
@@ -388,6 +446,8 @@ def train_traced(run: Run, trainer, state, step: int, keep_trace):
     run.trace = reduce_trace(trace_dir, keep_trace, run.notes)
     run.train["traced_steps"] = inside
     run.train["batch_at"] = trainer.batch_at
+    # the step's XLA module: jit_step on one chip, jit__step data-parallel
+    run.train["step_module"] = f"jit_{trainer.train_step.__name__}"
     return state, step
 
 
@@ -409,18 +469,22 @@ def reduce_trace(trace_dir: str, keep_trace: Optional[str], notes: dict):
     it to be kept there."""
     from . import tracing
     summary = tracing.reduce_dir(trace_dir)
-    notes["module_s"] = dict(sorted(summary.module_s.items(),
-                                    key=lambda kv: -kv[1])[:tracing.TOP])
+
+    def top(d):
+        return dict(sorted(d.items(), key=lambda kv: -kv[1])[:tracing.TOP])
+    notes["module_s"] = top(summary.module_s)
+    if tracing.collective_ops(summary):
+        notes["collective_ops_s"] = top(tracing.collective_ops(summary))
     if keep_trace is None:
         import shutil
         shutil.rmtree(trace_dir, ignore_errors=True)
     return summary
 
 
-def memory_peak() -> int:
-    import jax
-    stats = jax.devices()[0].memory_stats() or {}
-    return int(stats.get("peak_bytes_in_use", 0))
+def memory_peak(devices) -> int:
+    """Peak bytes in use on the fullest of ``devices``."""
+    return max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+               for d in devices)
 
 
 def peaks_for(kind: str, root: Path) -> dict:
@@ -442,12 +506,14 @@ def execute(name: str, seed: int, seconds: float, traced: bool, *,
     import jax
     devices = check_devices(cell["chips"]) if require_chip \
         else jax.devices()[:cell["chips"]]
+    if len(devices) < cell["chips"]:
+        raise NoChip(f"{cell['chips']} devices asked, {len(devices)} found")
     cfg = spec.load_config(bench, cell["config"], root)
     mix = spec.load_mix(cell["traffic"], root)
     proc = spec.process(mix["process"], root)
     cfg["program_cfg"], cfg["program_api"] = program_config(cfg)
     kind = devices[0].device_kind if require_chip else STAND_IN_KIND
-    run = Run(cell, cfg, mix, peaks_for(kind, root))
+    run = Run(cell, cfg, mix, peaks_for(kind, root), devices)
     counter = CompileCounter()
     BODIES[proc.ENTRY](run, proc, seed, seconds, traced, counter, t_start,
                        keep_trace)

@@ -1,42 +1,38 @@
 """Operations and minimum bytes, counted from the configuration's shapes
 and the ids a batch or wave really holds.  Nothing here depends on how
-the program implements a layer, so a count is the same for every PR."""
+the program implements a layer, so a count is the same for every PR.
+What differs by model family (its FLOPs and dense parameters) lives in
+the family's reference module, ``bench/reference/<family>.py``."""
 
 from __future__ import annotations
 
+from typing import Optional
+
 import numpy as np
 
-
-def _mlp_flops(dims) -> int:
-    return sum(2 * i * o for i, o in zip(dims[:-1], dims[1:]))
+from bench.reference.api import family
+from bench.reference.common import qr_rows, table_shapes
 
 
 def forward_flops(model: dict) -> int:
-    """Multiply-adds (as 2 FLOPs) of one example's forward pass through
-    the MLPs and the interaction (DLRM: the F(F-1)/2 pairwise dots) or the
-    cross layers (DCN: ``x . w``, ``x0 * s``, ``+ b + x``); the pooling
-    adds of the embeddings are not counted."""
-    d, f = model["emb_dim"], len(model["table_sizes"])
-    if model["family"] == "dlrm":
-        n = f + 1
-        return (_mlp_flops([model["dense_dim"], *model["bottom_mlp"], d])
-                + n * (n - 1) // 2 * 2 * d
-                + _mlp_flops([n * (n - 1) // 2 + d, *model["top_mlp"], 1]))
-    d0 = model["dense_dim"] + d * f
-    return (model["cross_layers"] * 5 * d0
-            + _mlp_flops([d0, *model["deep_mlp"]])
-            + _mlp_flops([d0 + model["deep_mlp"][-1], 1]))
+    """Multiply-adds (as 2 FLOPs) of one example's forward pass, as the
+    model's family counts them (``<family>.forward_flops``)."""
+    return family(model).forward_flops(model)
 
 
-def _qr_m(size: int, collisions: int) -> int:
-    return max(1, -(-size // max(1, collisions)))
+def dense_param_count(model: dict) -> int:
+    return family(model).dense_param_count(model)
 
 
-def touched_rows(model: dict, feature: int, ids: np.ndarray) -> int:
+def touched_rows(model: dict, feature: int, ids: np.ndarray,
+                 mask: Optional[np.ndarray] = None) -> int:
     """Distinct rows of feature ``feature``'s two QR tables that ``ids``
-    read: remainder rows ``id % m`` plus quotient rows ``id // m``."""
-    m = _qr_m(model["table_sizes"][feature], model["num_collisions"])
-    ids = np.asarray(ids, np.int64).ravel()
+    read: remainder rows ``id % m`` plus quotient rows ``id // m``.  With
+    a bag ``mask`` of ``ids``' shape, only slots whose mask is non-zero
+    count."""
+    m, _ = qr_rows(model["table_sizes"][feature], model["num_collisions"])
+    ids = np.asarray(ids, np.int64)
+    ids = ids.ravel() if mask is None else ids[np.asarray(mask) != 0]
     return len(np.unique(ids % m)) + len(np.unique(ids // m))
 
 
@@ -56,27 +52,41 @@ def embed_min_bytes(model: dict, ids: np.ndarray, lens) -> int:
 OPT_STATE_ARRAYS = {"adagrad": 1, "amsgrad": 3}
 
 
-def dense_param_count(model: dict) -> int:
-    d, f = model["emb_dim"], len(model["table_sizes"])
-    bias = lambda dims: sum(o for o in dims[1:])  # noqa: E731
-    if model["family"] == "dlrm":
-        n = f + 1
-        dims = [[model["dense_dim"], *model["bottom_mlp"], d],
-                [n * (n - 1) // 2 + d, *model["top_mlp"], 1]]
-        return sum(_mlp_flops(x) // 2 + bias(x) for x in dims)
-    d0 = model["dense_dim"] + d * f
-    dims = [[d0, *model["deep_mlp"]], [d0 + model["deep_mlp"][-1], 1]]
-    return (2 * d0 * model["cross_layers"]
-            + sum(_mlp_flops(x) // 2 + bias(x) for x in dims))
-
-
-def train_step_min_bytes(model: dict, optimizer: str, sparse: np.ndarray) -> int:
+def train_step_min_bytes(model: dict, optimizer: str, sparse: np.ndarray,
+                         mask: Optional[np.ndarray] = None) -> int:
     """Least bytes one training step must move: each table row the batch
     touches and every dense parameter is read and written, with its
     optimizer state, and its gradient is written and read, all f32.  Rows
     the batch does not touch need not move, so a sparse update can reach
-    this and a dense one cannot."""
+    this and a dense one cannot.  ``sparse`` is ``(B, F)``, or ``(B, F,
+    L)`` bags with their ``mask`` (padded slots touch nothing)."""
     per = 4 * (2 + 2 * OPT_STATE_ARRAYS[optimizer] + 2)
-    rows = sum(touched_rows(model, f, sparse[:, f])
+    rows = sum(touched_rows(model, f, sparse[:, f],
+                            None if mask is None else mask[:, f])
                for f in range(sparse.shape[1]))
     return per * (rows * model["emb_dim"] + dense_param_count(model))
+
+
+def grad_elements(model: dict) -> int:
+    """Elements of the whole gradient: every QR table and dense parameter."""
+    return (sum(r0 * d0 + r1 * d1 for (r0, d0), (r1, d1) in table_shapes(model))
+            + dense_param_count(model))
+
+
+def error_state_min_bytes(model: dict) -> int:
+    """Least bytes a step of an exchange with error feedback moves for it:
+    each replica's f32 residual of every gradient element, read and
+    written (a residual outlives the step that made it, so rows the batch
+    does not touch carry one too)."""
+    return 2 * 4 * grad_elements(model)
+
+
+WIRE_BYTES = {"int8": 1}
+
+
+def exchange_min_bytes(model: dict, compress: str, chips: int) -> int:
+    """Least bytes one chip must send in a data-parallel step whose
+    gradients are mean-all-reduced over ``chips``, every leaf in the
+    uniform mode ``compress``: ``2(n-1)/n`` of the gradient payload, 1 B
+    an element in int8; scales left out."""
+    return 2 * (chips - 1) * grad_elements(model) * WIRE_BYTES[compress] // chips

@@ -1,6 +1,7 @@
 """Model FLOPs of what the window completed (forward per scored request;
-forward and backward, 3x, per trained example) per second, over the
-chip's bf16 peak, in percent.  Counted from the configuration's shapes."""
+forward and backward, 3x, per trained example) per second, over the bf16
+peak of the cell's chips, in percent.  Counted from the configuration's
+shapes."""
 
 import numpy as np
 
@@ -8,7 +9,8 @@ from bench.metrics._counts import forward_flops
 
 
 def read(run):
-    model, peak = run.cfg["model"], run.peaks["bf16_flops_per_s"]
+    model = run.cfg["model"]
+    peak = run.cell["chips"] * run.peaks["bf16_flops_per_s"]
     if run.train is not None:
         rate, per = run.train["examples"] / run.window_s, 3 * forward_flops(model)
     elif run.serve is not None:

@@ -4,13 +4,14 @@ tables, served scores in blocks, and the first three training steps."""
 from __future__ import annotations
 
 import importlib
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .common import (bce_with_logits, key_from_seed, lookup, opt_init,
-                     opt_update, quantize_rows)
+from .common import (bce_with_logits, int_exchange, key_from_seed, lookup,
+                     opt_init, opt_update, quantize_rows)
 
 
 def family(model: dict):
@@ -56,36 +57,59 @@ def serve_logits(qparams, dense, idx, mask, model: dict, dtype=jnp.float32,
     return out
 
 
-def train_steps(params0, batches, model: dict, opt: dict, dtype=jnp.float32):
+def train_steps(params0, batches, model: dict, opt: dict, dtype=jnp.float32,
+                replicas: int = 1, bits: Optional[int] = None):
     """The configuration's optimizer over ``batches`` from ``params0``.
-    Returns each step's loss, the per-leaf norm of the first gradient and
-    the per-leaf norm of the parameters' change after the first and after
-    the last step."""
+    A batch's ``sparse`` is ``(B, F)``, or ``(B, F, L)`` bags with their
+    ``mask``.  Where the configuration states a gradient exchange
+    (``opt["compress"]``, "int8"), the batch is split into ``replicas``
+    shards of consecutive rows, each takes its own mean gradient, and the
+    optimizer gets their mean through ``common.int_exchange``, its errors
+    carried from step to step; ``bits`` gives the code another width.
+    Matmuls run at ``opt["matmul_precision"]``, the precision the
+    configuration states, or ``highest`` where it states none.  Returns each step's loss, the per-leaf norm of the first gradient as
+    the optimizer gets it and the per-leaf norm of the parameters' change
+    after the first and after the last step."""
     fam = family(model)
     cast = lambda t: jax.tree.map(lambda x: x.astype(dtype), t)  # noqa: E731
+    if opt.get("compress") is not None:
+        bits = bits or {"int8": 8}[opt["compress"]]
+    precision = opt.get("matmul_precision", "highest")
 
     def loss_fn(p, b):
-        feats = lookup(p["tables"], b["sparse"], model, dtype)
+        feats = lookup(p["tables"], b["sparse"], model, dtype,
+                       mask=b.get("mask"))
         logits = fam.dense_forward(p, b["dense"], feats, model)
         return bce_with_logits(logits, b["label"].astype(dtype))
 
     @jax.jit
-    def step(p, s, b, t):
-        with jax.default_matmul_precision("highest"):
-            loss, g = jax.value_and_grad(loss_fn)(p, b)
+    def step(p, s, e, b, t):
+        with jax.default_matmul_precision(precision):
+            if bits is None:
+                loss, g = jax.value_and_grad(loss_fn)(p, b)
+            else:
+                shards = jax.tree.map(lambda x: x.reshape(
+                    (replicas, x.shape[0] // replicas) + x.shape[1:]), b)
+                loss, g = jax.vmap(jax.value_and_grad(loss_fn),
+                                   in_axes=(None, 0))(p, shards)
+                loss = jnp.mean(loss)
+                g, e = int_exchange(g, e, bits)
+                g = jax.tree.map(lambda x: x.astype(dtype), g)
             gnorm = jnp.stack([jnp.linalg.norm(x.astype(jnp.float32))
                                for x in jax.tree.leaves(g)])
             p, s = opt_update(opt, g, s, p, t)
-        return p, s, loss.astype(jnp.float32), gnorm
+        return p, s, e, loss.astype(jnp.float32), gnorm
 
     change = jax.jit(lambda a, b: jnp.stack(
         [jnp.linalg.norm(x.astype(jnp.float32) - y)
          for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b))]))
     p = cast(params0)
     s = opt_init(opt, p, dtype)
+    e = None if bits is None else [jnp.zeros((replicas,) + x.shape, jnp.float32)
+                                   for x in jax.tree.leaves(p)]
     losses, first = [], None
     for t, b in enumerate(batches):
-        p, s, loss, gnorm = step(p, s, b, jnp.int32(t))
+        p, s, e, loss, gnorm = step(p, s, e, b, jnp.int32(t))
         losses.append(float(loss))
         if first is None:
             first = np.asarray(gnorm)

@@ -104,6 +104,15 @@ def lookup(tables: list[dict], idx, model: dict, dtype, mask=None):
     return jnp.stack(feats, axis=1)
 
 
+def mlp_flops(dims) -> int:
+    """Multiply-adds (as 2 FLOPs) of one example through an MLP ``dims``."""
+    return sum(2 * i * o for i, o in zip(dims[:-1], dims[1:]))
+
+
+def mlp_param_count(dims) -> int:
+    return sum(i * o + o for i, o in zip(dims[:-1], dims[1:]))
+
+
 def mlp(layers, x, final_linear: bool = False):
     for i, l in enumerate(layers):
         x = x @ l["w"].astype(x.dtype) + l["b"].astype(x.dtype)
@@ -147,6 +156,41 @@ def opt_update(opt: dict, grads, state, params, step: int):
             new_p.append(p - lr * mhat / (jnp.sqrt(vhat) + opt["eps"]))
             new_s.append({"m": m, "v": v, "vmax": vmax})
     return jax.tree.unflatten(treedef, new_p), new_s
+
+
+def int_exchange(grads, err, bits: int):
+    """The mean over ``n`` replicas of their gradients (each leaf ``(n,
+    *shape)``) as a data-parallel exchange in a ``bits``-wide integer code
+    gives it: each replica's gradient plus the error it carries is coded
+    on one scale all share (``max |v| / qmax``, rounded to nearest), the
+    codes are summed exactly, and their mean is coded again on a scale of
+    its own.  Error feedback at both levels: each replica carries what
+    its first code lost, and replica ``r`` also carries ``n`` times what
+    the second code lost on its block ``r`` of the leaf's rows (``n``
+    blocks of ``ceil(rows / n)``).  Returns the mean, replicated, and the
+    new errors."""
+    qmax = 2 ** (bits - 1) - 1
+    tiny = jnp.finfo(jnp.float32).tiny
+
+    def leaf(g, e):
+        n = g.shape[0]
+        v = g.astype(jnp.float32) + e
+        scale = jnp.maximum(jnp.max(jnp.abs(v)) / qmax, tiny)
+        q = jnp.clip(jnp.round(v / scale), -qmax, qmax)
+        y = jnp.sum(q, axis=0) * (scale / n)
+        scale2 = jnp.maximum(jnp.max(jnp.abs(y)) / qmax, tiny)
+        out = jnp.clip(jnp.round(y / scale2), -qmax, qmax) * scale2
+        rows = y.shape[0] if y.ndim else 1
+        block = jnp.arange(rows) // -(-rows // n)
+        own = (block[None, :] == jnp.arange(n)[:, None]).astype(jnp.float32)
+        own = own.reshape((n,) + y.shape[:1] + (1,) * (y.ndim - 1))
+        charged = q * scale - n * own * (y - out)
+        return out, v - charged
+
+    pairs = [leaf(g, e) for g, e in zip(jax.tree.leaves(grads), err)]
+    treedef = jax.tree.structure(grads)
+    return (jax.tree.unflatten(treedef, [o for o, _ in pairs]),
+            [e for _, e in pairs])
 
 
 def first_grad_norms(opt: dict, state) -> np.ndarray:

@@ -6,7 +6,8 @@ wraps its own calls into the program in host spans named ``bench.<what>``
 
 * device busy time: the union of the intervals in which an XLA op ran on
   a device, averaged over the devices that ran any;
-* device time per XLA module (jitted program), by module name;
+* device time per XLA module (jitted program), by module name, and per
+  XLA op, by op name;
 * ``breakdown``: the device ops that took most time, and the device's
   idle time split by the ``bench.*`` host span that covered it (the
   harness's spans do not nest; time no span covers is "no bench span").
@@ -23,6 +24,9 @@ import re
 from collections import defaultdict
 
 WINDOW_SPAN = "bench.window"
+# XLA's collective ops; an async one runs as its "-start" and "-done" halves
+COLLECTIVES = ("all-to-all", "all-gather", "all-reduce", "reduce-scatter",
+               "collective-permute")
 SPAN_PREFIX = "bench."
 TOP = 10
 
@@ -31,9 +35,10 @@ TOP = 10
 class TraceSummary:
     window_s: float
     busy_s: float
-    devices: int
+    devices: int              # the figures below are per device, averaged
     module_s: dict            # module name -> device seconds in the window
     module_calls: dict        # module name -> executions that began in it
+    op_s: dict                # op name -> device seconds in the window
     device_ops: list          # [[op name, seconds]], most first
     idle_gaps: list           # [[host span, seconds]], most first
 
@@ -140,9 +145,23 @@ def reduce_profile(pd) -> TraceSummary:
     return TraceSummary(
         window_s=(w1 - w0) * 1e-9, busy_s=busy_total * 1e-9 / n, devices=n,
         module_s={k: v / n for k, v in module_s.items()},
-        module_calls=dict(module_calls),
+        module_calls={k: v / n for k, v in module_calls.items()},
+        op_s={k: v / n for k, v in op_s.items()},
         device_ops=top({k: v / n for k, v in op_s.items()}),
         idle_gaps=top(idle))
+
+
+def is_collective(op: str) -> bool:
+    """``all-gather-start.3``, ``all_to_all.12`` and the like: an op named
+    for a collective kind, whole or one of its async halves (XLA names
+    some for their HLO opcode, some for the JAX primitive)."""
+    base = re.sub(r"\.\d+$", "", op).replace("_", "-")
+    return any(base in (k, k + "-start", k + "-done") for k in COLLECTIVES)
+
+
+def collective_ops(summary: TraceSummary) -> dict:
+    """Device seconds of each collective op in the window, per device."""
+    return {k: v for k, v in summary.op_s.items() if is_collective(k)}
 
 
 def reduce_dir(trace_dir: str) -> TraceSummary:

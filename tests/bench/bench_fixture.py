@@ -32,7 +32,8 @@ def reduced_copy(dst: Path, rates=(100.0, 400.0), batch: int = 64) -> Path:
         cfg["program"]["reduced_sizes"] = True
         cfg["model"]["table_sizes"] = REDUCED_SIZES
         cfg["limits"] = dict(LIMITS)
-        cfg["serve"]["max_batch"] = 8
+        if "serve" in cfg:
+            cfg["serve"]["max_batch"] = 8
         f.write_text(json.dumps(cfg))
     tdir = dst / "bench" / "traffic"
     for p in tdir.glob("*.json"):

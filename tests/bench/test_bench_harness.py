@@ -19,6 +19,8 @@ from bench import harness, spec, tracing
 SEED = 2 ** 31 + 5
 BENCH = json.loads((bf.REPO / "BENCHMARK.json").read_text())
 CELLS = [w["name"] for w in BENCH["workloads"]]
+# cells on several chips run on forced host devices: test_bench_dp.py
+ONE_CHIP = [w["name"] for w in BENCH["workloads"] if w["chips"] == 1]
 SMALL_TRACE = Path(__file__).parent / "data" / "train_step.xplane.pb"
 
 
@@ -27,7 +29,7 @@ def root(tmp_path_factory):
     return bf.reduced_copy(tmp_path_factory.mktemp("bench"))
 
 
-@pytest.mark.parametrize("cell", CELLS)
+@pytest.mark.parametrize("cell", ONE_CHIP)
 def test_cell_runs_at_reduced_size(root, cell):
     run, line = harness.execute(cell, SEED, 1.0, False, root=root,
                                 require_chip=False)
@@ -163,6 +165,95 @@ def test_new_traffic_process_runs_by_name_alone(tmp_path):
     assert all(p.read_bytes() == b for p, b in before.items())
 
 
+BAG_FAMILY = '''"""A throwaway reference family: DLRM's equations under a name of
+its own."""
+from bench.reference.dlrm import (dense_forward, dense_param_count,  # noqa: F401
+                                  forward_flops, init)
+'''
+
+BAG_BATCHES = '''"""Training batches of bags: ``bag`` slots a feature, the first
+``1 + floor(u * bag)`` of them live."""
+import jax
+import jax.numpy as jnp
+
+ENTRY = "train"
+
+
+def batch_fn(mix, model, seed):
+    from bench.reference.common import key_from_seed
+    sizes = jnp.asarray(model["table_sizes"], jnp.int32)[:, None]
+    b, f, bag = mix["batch"], len(model["table_sizes"]), mix["bag"]
+    base = key_from_seed(seed)
+
+    @jax.jit
+    def make_batch(step):
+        kd, ks, kn, kl = jax.random.split(jax.random.fold_in(base, step), 4)
+        u = jax.random.uniform(ks, (b, f, bag))
+        live = 1 + jnp.floor(jax.random.uniform(kn, (b, f, 1)) * bag)
+        return {"dense": jax.random.normal(kd, (b, model["dense_dim"])),
+                "sparse": jnp.minimum(jnp.floor(u ** mix["skew"] * sizes)
+                                      .astype(jnp.int32), sizes - 1),
+                "mask": (jnp.arange(bag) < live).astype(jnp.float32),
+                "label": jax.random.bernoulli(kl, 0.3, (b,)).astype(jnp.float32)}
+    return make_batch
+'''
+
+
+def test_new_family_with_bag_batches_runs_by_new_files_alone(tmp_path,
+                                                             monkeypatch):
+    """A multi-hot training configuration of a new family: its reference
+    module, traffic process, mix and configuration are new files, its cell
+    a new entry, and the program a new arch whose loss reads the bags'
+    mask.  The harness takes it with no file of ``bench/`` edited."""
+    import dataclasses
+    import types
+    from repro import configs
+    from repro.configs import dlrm_criteo
+    from repro.models.dlrm import dlrm_forward
+    from bench.reference.common import bce_with_logits
+
+    root = bf.reduced_copy(tmp_path)
+    before = {p: p.read_bytes() for p in (root / "bench").rglob("*")
+              if p.is_file()}
+    fam = types.ModuleType("bench.reference.dlrm_bags")
+    exec(BAG_FAMILY, fam.__dict__)
+    monkeypatch.setitem(sys.modules, "bench.reference.dlrm_bags", fam)
+
+    def api(cfg):
+        def loss_fn(p, b):
+            logits = dlrm_forward(p, b["dense"], b["sparse"], cfg,
+                                  mask=b["mask"])
+            loss = bce_with_logits(logits, b["label"])
+            return loss, {"bce": loss}
+        return dataclasses.replace(dlrm_criteo.api(cfg), loss_fn=loss_fn)
+    monkeypatch.setitem(configs.ARCHS, "dlrm-bags", types.SimpleNamespace(
+        config=dlrm_criteo.config, api=api))
+
+    (root / "bench" / "traffic" / "bag_batches.py").write_text(BAG_BATCHES)
+    (root / "bench" / "traffic" / "train-bags.json").write_text(json.dumps(
+        {"process": "bag_batches", "batch": 32, "bag": 4, "skew": 1.5}))
+    cfg = json.loads((root / "bench" / "configs" / "dlrm-criteo-kaggle.json")
+                     .read_text())
+    cfg["model"]["family"], cfg["program"]["arch"] = "dlrm_bags", "dlrm-bags"
+    (root / "bench" / "configs" / "dlrm-bags.json").write_text(json.dumps(cfg))
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    bench["configs"].append({"name": "dlrm-bags",
+                             "file": "bench/configs/dlrm-bags.json"})
+    bench["workloads"].append({"name": "dlrm-bags.train-bags",
+                               "config": "dlrm-bags", "traffic": "train-bags",
+                               "chips": 1})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    run, line = harness.execute("dlrm-bags.train-bags", SEED, 1.0, False,
+                                root=root, require_chip=False)
+    assert line["correct"], line
+    assert set(line["checks"]) == {"loss_gap", "grad_gap", "change_gap"}
+    batch = spec.process("bag_batches", root).batch_fn(
+        json.loads((root / "bench" / "traffic" / "train-bags.json").read_text()),
+        cfg["model"], SEED)(0)
+    assert batch["sparse"].ndim == 3 and 0 < float(batch["mask"].mean()) < 1
+    assert all(p.read_bytes() == b for p, b in before.items())
+
+
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 
@@ -232,3 +323,66 @@ def test_trace_reduction_by_hand():
     assert len(s.device_ops) == 10
     assert sum(v for _, v in s.device_ops) == pytest.approx(1382e-9, rel=1e-9)
     assert s.device_ops[0][0] == "slice.26"       # the longest op, 313 ns
+
+
+def test_exchange_and_mfu_readers_by_hand():
+    """The four-chip readers on a made-up trace summary: the collectives'
+    device time a step, the exchange's roofline share, and ``mfu`` over
+    the cell's chips (one chip reads as it always did)."""
+    assert [tracing.is_collective(op) for op in (
+        "all-to-all.3", "all_to_all.7", "all_gather", "all-gather-start",
+        "all-gather-done.12", "all-reduce.1", "reduce-scatter.4",
+        "collective-permute-done.2", "fusion.3", "all-reduce-fusion.1",
+        "copy-start.2")] == [True] * 8 + [False] * 3
+    model = {"family": "dlrm", "dense_dim": 2, "table_sizes": [5, 9],
+             "emb_dim": 2, "num_collisions": 4, "bottom_mlp": [3],
+             "top_mlp": [4]}
+    summary = tracing.TraceSummary(
+        window_s=1.0, busy_s=0.5, devices=4, module_s={}, module_calls={},
+        op_s={"fusion.1": 0.3, "all-to-all.2": 0.1, "all-gather-start.3": 0.05,
+              "all-gather-done.3": 0.05}, device_ops=[], idle_gaps=[])
+    peaks = {"ici_bytes_per_s": 1000.0, "bf16_flops_per_s": 1e6}
+    run = harness.Run({"chips": 4}, {"model": model,
+                                     "train": {"compress": "int8"}},
+                      {}, peaks, trace=summary, window_s=2.0,
+                      train={"traced_steps": [7, 8], "examples": 10})
+    read = lambda n: spec.reader(n)(run)  # noqa: E731
+    # 0.2 s of collectives a chip over 2 steps; fusion.1 is no collective
+    assert read("collective_ms_per_step.dp4") == pytest.approx(100.0)
+    # 102 B a step (test_exchange_bytes_by_hand), 2 steps, 0.2 s, 1000 B/s
+    assert read("exchange_roofline.dp4") == pytest.approx(100 * 204 / 0.2 / 1000)
+    # 84 FLOPs forward (test_counts_by_hand), x3, 5 examples/s, 4 chips
+    assert read("mfu.dp4") == pytest.approx(100 * 5 * 252 / 4e6)
+    run.cell = {"chips": 1}
+    assert read("mfu.train") == pytest.approx(100 * 5 * 252 / 1e6)
+    assert read("collective_ms_per_step.dp4") is None
+
+
+@pytest.mark.parametrize("chips,module,least", [(1, "jit_step", 1440),
+                                                (4, "jit__step", 1440 + 544)])
+def test_train_step_roofline_by_hand(chips, module, least):
+    """Two steps of a batch of two examples on the small model of
+    ``test_counts_by_hand``: feature 0's ids 0, 1 touch 2 remainder rows
+    and 1 quotient row, feature 1's ids 4, 8 touch 2 and 2; 7 rows of 2
+    and 46 dense parameters, each read and written with its Adagrad
+    accumulator and gradient, 24 B: 1440 B.  The data-parallel step (its
+    module ``jit__step``) also reads and writes the f32 residual of all
+    68 gradient elements: 544 B more."""
+    model = {"family": "dlrm", "dense_dim": 2, "table_sizes": [5, 9],
+             "emb_dim": 2, "num_collisions": 4, "bottom_mlp": [3],
+             "top_mlp": [4]}
+    train = {"name": "adagrad"}
+    if chips > 1:
+        train["compress"] = "int8"
+    summary = tracing.TraceSummary(
+        window_s=1.0, busy_s=0.5, devices=chips, module_s={module: 0.5},
+        module_calls={module: 2}, op_s={}, device_ops=[], idle_gaps=[])
+    batch = {"sparse": np.array([[0, 4], [1, 8]])}
+    run = harness.Run({"chips": chips}, {"model": model, "train": train}, {},
+                      {"hbm_bytes_per_s": 1000.0}, trace=summary,
+                      train={"traced_steps": [5, 6], "step_module": module,
+                             "batch_at": lambda s: batch})
+    got = spec.reader("train_step_roofline")(run)
+    assert got == pytest.approx(100 * least * 2 / 0.5 / 1000)
+    run.train["step_module"] = "jit_other"
+    assert spec.reader("train_step_roofline")(run) is None

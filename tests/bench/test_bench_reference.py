@@ -21,7 +21,8 @@ def config(name: str) -> dict:
     cfg = json.loads((bf.REPO / "bench" / "configs" / f"{name}.json").read_text())
     cfg["program"]["reduced_sizes"] = True
     cfg["model"]["table_sizes"] = bf.REDUCED_SIZES
-    cfg["serve"]["max_batch"] = 8
+    if "serve" in cfg:
+        cfg["serve"]["max_batch"] = 8
     cfg["program_cfg"], cfg["program_api"] = harness.program_config(cfg)
     return cfg
 
@@ -99,6 +100,28 @@ def test_trainer_steps_match_reference_and_bf16_fails(name):
     assert any(v > lim for v, lim in ctl.values()), ctl
 
 
+def test_dp_control_fails_the_committed_limits():
+    """The four-chip cell's controls against its reference (the int8
+    exchange over four shards of the batch), at reduced size: the
+    reference in bfloat16, and the reference with its exchange one
+    precision lower, in int4, each fail a committed limit.  (On the chip
+    at the cell's size: PERF.md.)"""
+    cfg = config("dlrm-criteo-kaggle-dp4")
+    model, opt = cfg["model"], cfg["train"]
+    make_batch = spec.process("device_batches").batch_fn(
+        {"batch": 64, "skew": 1.5, "label_noise": 0.5}, model, SEED)
+    batches = [make_batch(t) for t in range(3)]
+    params0 = ref.make_params(SEED, model)
+    want = ref.train_steps(params0, batches, model, opt, replicas=4)
+    limits = json.loads((bf.REPO / "bench" / "configs"
+                         / "dlrm-criteo-kaggle-dp4.json").read_text())["limits"]
+    for kw in ({"dtype": jnp.bfloat16}, {"bits": 4}):
+        ctl = harness.compared(harness.train_numbers(
+            ref.train_steps(params0, batches, model, opt, replicas=4, **kw),
+            want), limits)
+        assert any(v > lim for v, lim in ctl.values()), (kw, ctl)
+
+
 def test_counts_by_hand():
     model = {"family": "dlrm", "dense_dim": 2, "table_sizes": [5, 9],
              "emb_dim": 2, "num_collisions": 4, "bottom_mlp": [3],
@@ -124,3 +147,128 @@ def test_counts_by_hand():
     # Adagrad: 4 B * (2 + 2 + 2) per value; (8 rows * 2 + 46) values
     assert _counts.train_step_min_bytes(model, "adagrad", sparse) == 24 * 62
     assert _counts.train_step_min_bytes(model, "amsgrad", sparse) == 40 * 62
+
+
+def test_bag_batches_train_as_their_live_slots():
+    """A bag batch with one live slot a bag trains as the one-hot batch;
+    ids under a zero mask change nothing."""
+    model, opt = config("dlrm-criteo-kaggle")["model"], {"name": "adagrad",
+                                                          "lr": 1e-2, "eps": 1e-10}
+    make_batch = spec.process("device_batches").batch_fn(
+        {"batch": 32, "skew": 1.5, "label_noise": 0.5}, model, SEED)
+    onehot = [jax.device_get(make_batch(t)) for t in range(2)]
+    rng = np.random.default_rng(SEED)
+    sizes = np.asarray(model["table_sizes"])
+
+    def bags(b, pad):
+        n, f = b["sparse"].shape
+        junk = (rng.random((n, f, pad)) * sizes[:, None]).astype(np.int32)
+        return dict(b, sparse=np.concatenate([b["sparse"][..., None], junk], -1),
+                    mask=np.concatenate([np.ones((n, f, 1), np.float32),
+                                         np.zeros((n, f, pad), np.float32)], -1))
+    params0 = ref.make_params(SEED, model)
+    want = ref.train_steps(params0, onehot, model, opt)
+    for pad in (0, 3):
+        got = ref.train_steps(params0, [bags(b, pad) for b in onehot], model, opt)
+        for k in want:
+            np.testing.assert_allclose(got[k], want[k], rtol=1e-6, atol=1e-9)
+
+
+def test_counts_of_the_kaggle_configurations_are_pinned():
+    """The counts the metrics divide by, for both Kaggle configurations:
+    one example's forward FLOPs, the dense parameters, and a training
+    step's least bytes on a batch drawn from a fixed seed."""
+    rng = np.random.default_rng(20261019)
+    u = rng.random((2048, 26))
+    pinned = {"dlrm-criteo-kaggle": (959968, 475985, 20469912),
+              "dcn-criteo-kaggle": (748064, 373578, 30020240)}
+    for name, want in pinned.items():
+        cfg = json.loads((bf.REPO / "bench" / "configs" / f"{name}.json")
+                         .read_text())
+        model = cfg["model"]
+        sizes = np.asarray(model["table_sizes"])
+        sparse = np.minimum(np.floor(u ** 1.5 * sizes).astype(np.int64),
+                            sizes - 1)
+        got = (_counts.forward_flops(model), _counts.dense_param_count(model),
+               _counts.train_step_min_bytes(model, cfg["train"]["name"], sparse))
+        assert got == want, name
+        # the same batch as one-slot bags, with masked slots that would
+        # touch other rows, counts the same
+        pad = np.stack([sparse, np.broadcast_to(sizes - 1, sparse.shape)], -1)
+        mask = np.concatenate([np.ones_like(sparse)[..., None],
+                               np.zeros_like(sparse)[..., None]], -1)
+        assert _counts.train_step_min_bytes(model, cfg["train"]["name"], pad,
+                                            mask) == want[2]
+
+
+def test_exchange_bytes_by_hand():
+    model = {"family": "dlrm", "dense_dim": 2, "table_sizes": [5, 9],
+             "emb_dim": 2, "num_collisions": 4, "bottom_mlp": [3],
+             "top_mlp": [4]}
+    # tables: S=5 -> 2+3 rows, S=9 -> 3+3 rows, 11 rows * 2 = 22 elements;
+    # 46 dense parameters; 68 elements, 2*(4-1)/4 of them a chip sends
+    assert _counts.exchange_min_bytes(model, "int8", 4) == 102
+    assert _counts.grad_elements(model) == 68
+    # each replica's f32 residual of every element, read and written
+    assert _counts.error_state_min_bytes(model) == 544
+    assert _counts.exchange_min_bytes(model, "int8", 1) == 0
+
+
+def test_unknown_family_has_no_counts():
+    with pytest.raises(ModuleNotFoundError):
+        _counts.forward_flops({"family": "no_such_family", "table_sizes": [5]})
+
+
+@pytest.mark.parametrize("name,key", [("dlrm-criteo-kaggle", "top_mlp"),
+                                      ("dlrm-criteo-kaggle", "bottom_mlp"),
+                                      ("dcn-criteo-kaggle", "cross_layers"),
+                                      ("dcn-criteo-kaggle", "deep_mlp")])
+def test_program_config_refuses_a_width_it_does_not_run(name, key):
+    cfg = json.loads((bf.REPO / "bench" / "configs" / f"{name}.json").read_text())
+    harness.program_config(cfg)
+    v = cfg["model"][key]
+    cfg["model"][key] = [*v[:-1], v[-1] * 2] if isinstance(v, list) else v + 1
+    with pytest.raises(ValueError, match="differs"):
+        harness.program_config(cfg)
+
+
+@pytest.mark.parametrize("chips,compress,ok", [(4, "int8", True),
+                                               (1, None, True),
+                                               (4, None, False),
+                                               (1, "int8", False)])
+def test_train_cell_chips_and_compression_go_together(chips, compress, ok):
+    """A cell on several chips runs the data-parallel step and needs a
+    compression policy; a one-chip cell runs the plain step and has none."""
+    cfg = {"train": {"name": "adagrad"}}
+    if compress:
+        cfg["train"]["compress"] = compress
+    cell = {"name": "x.y", "chips": chips}
+    if ok:
+        assert harness.dp_compress(cell, cfg) == compress
+    else:
+        with pytest.raises(ValueError, match="compress"):
+            harness.dp_compress(cell, cfg)
+
+
+def test_int_exchange_keeps_what_it_drops():
+    """The reference's integer exchange: the mean it gives is within half
+    a step of each code of the true mean, and the errors it hands on hold
+    exactly what it dropped, so the replicas' errors plus ``n`` times the
+    mean add up to the replicas' gradients (error feedback at both
+    levels telescopes)."""
+    from bench.reference.common import int_exchange
+    rng = np.random.default_rng(SEED)
+    g = [jnp.asarray(rng.standard_normal((4, 7, 3)) * rng.random((1, 7, 1)),
+                     jnp.float32),
+         jnp.asarray(rng.standard_normal((4, 5)), jnp.float32)]
+    e = [jnp.zeros_like(x) for x in g]
+    for bits in (8, 4):
+        out, err = int_exchange(g, e, bits)
+        for x, o, r in zip(g, out, err):
+            qmax = 2 ** (bits - 1) - 1
+            step1 = float(jnp.max(jnp.abs(x))) / qmax
+            assert np.all(np.abs(np.asarray(o) - np.asarray(x).mean(0))
+                          <= step1 / 2 + float(jnp.max(jnp.abs(o))) / qmax / 2
+                          + 1e-6)
+            np.testing.assert_allclose(np.asarray(r).sum(0) + 4 * np.asarray(o),
+                                       np.asarray(x).sum(0), atol=1e-5)
