@@ -19,6 +19,7 @@ FAULTS = ("unchanged_state", "half_batch", "no_exchange")
 
 @contextlib.contextmanager
 def plant(fault: str):
+    import jax
     from repro.train import loop
     if fault not in FAULTS:
         raise ValueError(f"unknown fault {fault!r}; one of {FAULTS}")
@@ -34,7 +35,7 @@ def plant(fault: str):
             def run(state, batch):
                 if fault == "half_batch":
                     half = batch["label"].shape[0] // 2
-                    return step(state, {k: v[:half] for k, v in batch.items()})
+                    return step(state, jax.tree.map(lambda v: v[:half], batch))
                 new, met = step(state, batch)
                 return dict(state, step=new["step"]), met
             return run

@@ -11,7 +11,8 @@ module ``bench/traffic/<process>.py`` that turns them into traffic
   (``bench.serve.OpenLoop`` for an open loop; a closed loop brings its own
   class with the same attributes);
 * for ``"train"``: ``batch_fn(mix, model, seed)``, a jitted ``step ->
-  {dense, sparse, label}``.
+  {dense, sparse, label}`` (``sparse`` in a form ``reference.api.
+  train_steps`` takes, with its ``mask`` where it has one).
 
 Adding a process is adding its module; nothing here changes.
 """
@@ -19,6 +20,7 @@ Adding a process is adding its module; nothing here changes.
 from __future__ import annotations
 
 import dataclasses
+import zlib
 
 import numpy as np
 
@@ -82,3 +84,11 @@ def draw_requests(mix: dict, model: dict, rng: np.random.Generator,
     ids = np.minimum(np.floor(u ** mix["skew"] * sizes).astype(np.int64),
                      sizes - 1)
     return Requests(due, dense, ids, np.cumsum(lens)[:-1])
+
+
+def planted(seed: int, tag: str, shape):
+    """Weights ``N(0, 1/shape[0])`` of the planted logistic model that
+    labels training batches, drawn from ``seed`` and ``tag``."""
+    import jax
+    key = jax.random.PRNGKey(zlib.crc32(f"{seed}:{tag}".encode()) % (2 ** 31))
+    return jax.random.normal(key, shape) / np.sqrt(shape[0])

@@ -374,7 +374,7 @@ def train_cell(run: Run, proc, seed: int, seconds: float, traced: bool,
         state, met = trainer.train_step(state, trainer.batch_at(t))
         losses.append(float(met["loss"]))
         if t == 0:
-            first = first_grad_norms(opt, state["opt"])
+            first = first_grad_norms(opt, state["opt"], state["params"])
             change1 = np.asarray(norms(state["params"], params0))
     change = np.asarray(norms(state["params"], params0))
     del params0

@@ -11,7 +11,7 @@ from typing import Optional
 import numpy as np
 
 from bench.reference.api import family
-from bench.reference.common import qr_rows, table_shapes
+from bench.reference.common import qr_rows, rowwise, table_shapes
 
 
 def forward_flops(model: dict) -> int:
@@ -52,19 +52,29 @@ def embed_min_bytes(model: dict, ids: np.ndarray, lens) -> int:
 OPT_STATE_ARRAYS = {"adagrad": 1, "amsgrad": 3}
 
 
-def train_step_min_bytes(model: dict, optimizer: str, sparse: np.ndarray,
+def train_step_min_bytes(model: dict, train: dict, sparse,
                          mask: Optional[np.ndarray] = None) -> int:
     """Least bytes one training step must move: each table row the batch
     touches and every dense parameter is read and written, with its
-    optimizer state, and its gradient is written and read, all f32.  Rows
-    the batch does not touch need not move, so a sparse update can reach
-    this and a dense one cannot.  ``sparse`` is ``(B, F)``, or ``(B, F,
-    L)`` bags with their ``mask`` (padded slots touch nothing)."""
-    per = 4 * (2 + 2 * OPT_STATE_ARRAYS[optimizer] + 2)
-    rows = sum(touched_rows(model, f, sparse[:, f],
-                            None if mask is None else mask[:, f])
-               for f in range(sparse.shape[1]))
-    return per * (rows * model["emb_dim"] + dense_param_count(model))
+    optimizer state, and its gradient is written and read, all f32.
+    ``train`` is the configuration's: its optimizer's state arrays, one
+    value an element, or one a table row where its tables are row-wise.
+    Rows the batch does not touch need not move, so a sparse update can
+    reach this and a dense one cannot.  ``sparse`` is ``(B, F)``, ``(B,
+    F, L)`` bags with their ``mask`` (padded slots touch nothing), or the
+    list form, ``F`` arrays ``(B, L_f)`` with every slot live."""
+    per = 4 * (2 + 2 * OPT_STATE_ARRAYS[train["name"]] + 2)
+    if isinstance(sparse, (list, tuple)):
+        bags = [(np.asarray(ids), None) for ids in sparse]
+    else:
+        sparse = np.asarray(sparse)
+        mask = None if mask is None else np.asarray(mask)
+        bags = [(sparse[:, f], None if mask is None else mask[:, f])
+                for f in range(sparse.shape[1])]
+    rows = sum(touched_rows(model, f, ids, m) for f, (ids, m) in enumerate(bags))
+    d = model["emb_dim"]
+    table = rows * (4 * 4 * d + 4 * 2) if rowwise(train) else rows * d * per
+    return table + per * dense_param_count(model)
 
 
 def grad_elements(model: dict) -> int:

@@ -3,7 +3,8 @@ least bytes of the steps that ran in it over the device time of the step
 program, per chip, over the chip's HBM bandwidth, in percent.  The least
 bytes (``_counts``): the rows that live bag slots of the step's whole
 batch touch and the dense parameters, with their optimizer state and
-gradients (``train_step_min_bytes``); a data-parallel step with an
+gradients (``train_step_min_bytes``: a batch's ``sparse`` in any of its
+forms, a row-wise optimizer's state by the row); a data-parallel step with an
 exchange under error feedback adds its residual state
 (``error_state_min_bytes``), as every replica keeps all of it."""
 
@@ -23,10 +24,8 @@ def read(run):
         return None
     model, train = run.cfg["model"], run.cfg["train"]
     batches = (run.train["batch_at"](s) for s in steps)
-    per_step = np.mean([
-        train_step_min_bytes(model, train["name"], np.asarray(b["sparse"]),
-                             None if "mask" not in b else np.asarray(b["mask"]))
-        for b in batches])
+    per_step = np.mean([train_step_min_bytes(model, train, b["sparse"],
+                                             b.get("mask")) for b in batches])
     if train.get("compress") is not None:
         per_step += error_state_min_bytes(model)
     return 100.0 * per_step * calls / secs / run.peaks["hbm_bytes_per_s"]

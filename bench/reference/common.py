@@ -23,6 +23,13 @@ def qr_rows(size: int, collisions: int) -> tuple[int, int]:
     return m, -(-size // m)
 
 
+def qr_ids(model: dict, f: int, ids):
+    """Remainder and quotient rows ``(id % m, id // m)`` of feature
+    ``f``'s ids."""
+    m, _ = qr_rows(model["table_sizes"][f], model["num_collisions"])
+    return ids % m, ids // m
+
+
 def table_shapes(model: dict) -> list[tuple[tuple[int, int], tuple[int, int]]]:
     d = model["emb_dim"]
     return [((m, d), (q, d)) for m, q in
@@ -83,24 +90,41 @@ def dequant(t: dict, rows, dtype):
     return ((q - zp) * sc).astype(dtype)
 
 
+def feature_ids(idx, f: int):
+    """Feature ``f``'s ids in a batch's ``sparse``: ``(B,)`` of ``(B, F)``,
+    ``(B, L)`` of ``(B, F, L)`` bags, or the ``f``-th array ``(B, L_f)``
+    of the list form."""
+    return idx[f] if isinstance(idx, (list, tuple)) else idx[:, f]
+
+
+def pool(e, idx, mask, f: int, dtype):
+    """Feature ``f``'s rows ``e`` pooled: one id's row ``(B, D)`` as it is;
+    a bag's ``(B, L, D)`` summed over its slots, those under a zero
+    ``mask`` adding nothing (``(B, F, L)`` bags) or every slot live (the
+    list form)."""
+    if isinstance(idx, (list, tuple)):
+        return jnp.sum(e, axis=1)
+    if idx.ndim == 3:
+        return jnp.sum(e * mask[:, f, :, None].astype(dtype), axis=1)
+    return e
+
+
 def lookup(tables: list[dict], idx, model: dict, dtype, mask=None):
     """Pooled QR rows ``(B, F, D)``.  ``idx`` is ``(B, F)`` (one id per
-    feature) or ``(B, F, L)`` with ``mask`` (bags; masked slots add
-    nothing).  Quantised tables (dicts with ``q``) are dequantised row by
-    row; float tables are read as they are."""
+    feature), ``(B, F, L)`` with ``mask`` (bags padded to the widest;
+    masked slots add nothing), or the list form: ``F`` arrays ``(B,
+    L_f)``, bags of fixed widths with every slot live.  Quantised tables
+    (dicts with ``q``) are dequantised row by row; float tables are read
+    as they are."""
     feats = []
     for f, t in enumerate(tables):
-        m, _ = qr_rows(model["table_sizes"][f], model["num_collisions"])
-        ids = idx[:, f]
-        r, q = ids % m, ids // m
+        r, q = qr_ids(model, f, feature_ids(idx, f))
         if isinstance(t["table_0"], dict):
             e = dequant(t["table_0"], r, dtype) * dequant(t["table_1"], q, dtype)
         else:
             e = (jnp.take(t["table_0"].astype(dtype), r, axis=0)
                  * jnp.take(t["table_1"].astype(dtype), q, axis=0))
-        if idx.ndim == 3:
-            e = jnp.sum(e * mask[:, f, :, None].astype(dtype), axis=1)
-        feats.append(e)
+        feats.append(pool(e, idx, mask, f, dtype))
     return jnp.stack(feats, axis=1)
 
 
@@ -128,22 +152,46 @@ def bce_with_logits(logits, labels):
 
 # ---------------------------------------------------------------- optimizers
 
+def rowwise(opt: dict) -> bool:
+    """Whether the configuration's optimizer keeps one accumulator per
+    table row (``"tables": "rowwise"``, Adagrad only) rather than one per
+    element.  Any other ``tables`` raises."""
+    mode = opt.get("tables")
+    if mode not in (None, "rowwise") or (mode and opt["name"] != "adagrad"):
+        raise ValueError(f"tables {mode!r} under optimizer {opt['name']!r}: "
+                         "only Adagrad has a row-wise form")
+    return mode == "rowwise"
+
+
+def table_leaves(params) -> list[bool]:
+    """For each leaf of ``params``, in leaf order, whether it is a QR
+    table (a leaf under ``"tables"``)."""
+    return [getattr(path[0], "key", None) == "tables"
+            for path, _ in jax.tree_util.tree_flatten_with_path(params)[0]]
+
+
 def opt_init(opt: dict, params, dtype):
     names = {"adagrad": ("acc",), "amsgrad": ("m", "v", "vmax")}[opt["name"]]
-    return [{n: jnp.zeros(p.shape, dtype) for n in names}
-            for p in jax.tree.leaves(params)]
+    rows = rowwise(opt)
+    return [{n: jnp.zeros((p.shape[0], 1) if rows and tab else p.shape, dtype)
+             for n in names}
+            for p, tab in zip(jax.tree.leaves(params), table_leaves(params))]
 
 
 def opt_update(opt: dict, grads, state, params, step: int):
     """One step of the configuration's optimizer: Adagrad (Duchi et al.
-    2011) or AMSGrad (Reddi et al. 2018) with Adam's bias correction."""
+    2011), its tables row-wise where the configuration says so (one
+    accumulator a row, ``acc += mean(g**2)`` over the row), or AMSGrad
+    (Reddi et al. 2018) with Adam's bias correction."""
     gl, treedef = jax.tree.flatten(grads)
     pl = jax.tree.leaves(params)
+    rows = [rowwise(opt) and tab for tab in table_leaves(params)]
     lr = opt["lr"]
     new_p, new_s = [], []
-    for g, s, p in zip(gl, state, pl):
+    for g, s, p, row in zip(gl, state, pl, rows):
         if opt["name"] == "adagrad":
-            acc = s["acc"] + g * g
+            sq = jnp.mean(g * g, axis=1, keepdims=True) if row else g * g
+            acc = s["acc"] + sq
             new_p.append(p - lr * g / (jnp.sqrt(acc) + opt["eps"]))
             new_s.append({"acc": acc})
         else:
@@ -193,13 +241,17 @@ def int_exchange(grads, err, bits: int):
             [e for _, e in pairs])
 
 
-def first_grad_norms(opt: dict, state) -> np.ndarray:
+def first_grad_norms(opt: dict, state, params) -> np.ndarray:
     """Per-leaf norm of the first gradient, read back from an optimizer
     state after exactly one step from zero state: Adagrad's accumulator is
-    ``g**2``, AMSGrad's first moment is ``(1 - b1) g``."""
+    the mean of ``g**2`` over the elements each entry stands for (one, or
+    a table row's ``D`` where it is row-wise: ``params`` give the leaves'
+    sizes), AMSGrad's first moment is ``(1 - b1) g``."""
     if opt["name"] == "adagrad":
-        return np.asarray([float(jnp.sqrt(jnp.sum(s["acc"].astype(jnp.float32))))
-                           for s in state])
+        return np.asarray([
+            float(jnp.sqrt(jnp.sum(s["acc"].astype(jnp.float32))
+                           * (p.size // s["acc"].size)))
+            for s, p in zip(state, jax.tree.leaves(params))])
     return np.asarray([float(jnp.linalg.norm(s["m"].astype(jnp.float32)))
                        / (1 - opt["b1"]) for s in state])
 

@@ -12,6 +12,8 @@ can be chosen and their limits set.
 One JSON line per seed: each side's losses, and per leaf the norm of the
 first gradient and of the parameters' change after one and after three
 steps; with ``numbers`` each side's numbers as ``correct`` reads them.
+A cell's batches may take any form the harness takes (the list form of
+fixed-width bags among them); the half batch halves each array of it.
 """
 
 import argparse
@@ -40,7 +42,7 @@ def program_side(run, trainer, start, weights):
         state, met = trainer.train_step(state, trainer.batch_at(t))
         losses.append(float(met["loss"]))
         if t == 0:
-            first = first_grad_norms(opt, state["opt"])
+            first = first_grad_norms(opt, state["opt"], state["params"])
         if t in (0, 2):
             change.append(leaf_norms(jax.tree.map(lambda a, b: a - b,
                                                   state["params"], params0)))
@@ -101,7 +103,7 @@ def main() -> int:
             del trainer
         # the batches the program stepped on, whole, on the reference's device
         batches = [jax.device_get(batch_at(t)) for t in range(3)]
-        half = [{k: v[: v.shape[0] // 2] for k, v in b.items()} for b in batches]
+        half = [jax.tree.map(lambda v: v[: v.shape[0] // 2], b) for b in batches]
         n = cell["chips"]
         out.update(reference=ref_side(cfg, batches, weights, n, jnp.float32),
                    control=ref_side(cfg, batches, weights, n, jnp.bfloat16))

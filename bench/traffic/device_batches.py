@@ -5,18 +5,12 @@ model over the dense features and the ids, as ``data/criteo.py``'s
 
 from __future__ import annotations
 
-import zlib
-
 import jax
 import jax.numpy as jnp
-import numpy as np
+
+from bench.generator import planted
 
 ENTRY = "train"
-
-
-def _planted(seed: int, tag: str, shape):
-    key = jax.random.PRNGKey(zlib.crc32(f"{seed}:{tag}".encode()) % (2 ** 31))
-    return jax.random.normal(key, shape) / np.sqrt(shape[0])
 
 
 def batch_fn(mix: dict, model: dict, seed: int):
@@ -25,9 +19,9 @@ def batch_fn(mix: dict, model: dict, seed: int):
     sizes = jnp.asarray(model["table_sizes"], jnp.int32)
     b, f = mix["batch"], len(model["table_sizes"])
     base = key_from_seed(seed)
-    w_dense = _planted(seed, "wd", (model["dense_dim"],))
-    a = _planted(seed, "a", (f,))
-    c = _planted(seed, "c", (f,)) * 5.0
+    w_dense = planted(seed, "wd", (model["dense_dim"],))
+    a = planted(seed, "a", (f,))
+    c = planted(seed, "c", (f,)) * 5.0
 
     @jax.jit
     def make_batch(step):

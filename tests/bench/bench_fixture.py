@@ -46,6 +46,8 @@ def reduced_copy(dst: Path, rates=(100.0, 400.0), batch: int = 64) -> Path:
                 mix["rate_rps"] = rates[1]
         else:
             mix["batch"] = batch
+            if mix.get("bag_lengths"):
+                mix["bag_lengths"] = MULTIHOT
         p.write_text(json.dumps(mix))
     (dst / "BENCHMARK.json").write_text(json.dumps(bench))
     return dst

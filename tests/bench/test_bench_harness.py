@@ -254,6 +254,70 @@ def test_new_family_with_bag_batches_runs_by_new_files_alone(tmp_path,
     assert all(p.read_bytes() == b for p, b in before.items())
 
 
+def test_list_form_rowwise_cell_runs_by_new_files_alone(tmp_path,
+                                                       monkeypatch):
+    """A multi-hot configuration in MLPerf's form: fixed-width bags in the
+    list form (``device_bags``), row-wise Adagrad on the tables, and a
+    reference in blocks.  Its mix and configuration are new files, its
+    cell a new entry, and the program a new arch whose loss pads the list
+    form and whose optimizer is the program's ``partitioned`` row-wise
+    one.  The harness takes it with no file of ``bench/`` edited."""
+    import dataclasses
+    import types
+    import jax.numpy as jnp
+    from repro import configs
+    from repro.configs import dlrm_criteo
+    from repro.models.dlrm import dlrm_forward
+    from repro.optim.optimizers import adagrad, partitioned, rowwise_adagrad
+    from bench.reference import api as ref
+    from bench.reference.common import bce_with_logits
+
+    root = bf.reduced_copy(tmp_path)
+    before = {p: p.read_bytes() for p in (root / "bench").rglob("*")
+              if p.is_file()}
+    monkeypatch.setattr(ref, "BLOCK", 16)     # the batch of 64 in 4 blocks
+
+    def api(cfg):
+        def loss_fn(p, b):
+            widths = [x.shape[1] for x in b["sparse"]]
+            idx = jnp.stack([jnp.pad(x, ((0, 0), (0, max(widths) - w)))
+                             for x, w in zip(b["sparse"], widths)], axis=1)
+            mask = (np.arange(max(widths)) < np.asarray(widths)[:, None])
+            logits = dlrm_forward(p, b["dense"], idx, cfg,
+                                  mask=jnp.asarray(mask, jnp.float32)[None])
+            loss = bce_with_logits(logits, b["label"])
+            return loss, {"bce": loss}
+        opt = partitioned([(lambda path: "tables" in path,
+                            rowwise_adagrad(1e-2))], adagrad(1e-2))
+        return dataclasses.replace(dlrm_criteo.api(cfg), loss_fn=loss_fn,
+                                   optimizer=opt)
+    monkeypatch.setitem(configs.ARCHS, "dlrm-list", types.SimpleNamespace(
+        config=dlrm_criteo.config, api=api))
+
+    (root / "bench" / "traffic" / "train-list.json").write_text(json.dumps(
+        {"process": "device_bags", "batch": 64, "bag_lengths": bf.MULTIHOT,
+         "skew": 1.5, "label_noise": 0.5}))
+    cfg = json.loads((root / "bench" / "configs" / "dlrm-criteo-kaggle.json")
+                     .read_text())
+    cfg["program"]["arch"] = "dlrm-list"
+    cfg["train"] = {"name": "adagrad", "tables": "rowwise", "lr": 1e-2,
+                    "eps": 1e-10}
+    (root / "bench" / "configs" / "dlrm-list.json").write_text(json.dumps(cfg))
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    bench["configs"].append({"name": "dlrm-list",
+                             "file": "bench/configs/dlrm-list.json"})
+    bench["workloads"].append({"name": "dlrm-list.train-list",
+                               "config": "dlrm-list", "traffic": "train-list",
+                               "chips": 1})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    run, line = harness.execute("dlrm-list.train-list", SEED, 1.0, False,
+                                root=root, require_chip=False)
+    assert line["correct"], line
+    assert set(line["checks"]) == {"loss_gap", "grad_gap", "change_gap"}
+    assert all(v["value"] < 1e-4 for v in line["checks"].values()), line
+    assert all(p.read_bytes() == b for p, b in before.items())
+
+
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 
@@ -386,3 +450,25 @@ def test_train_step_roofline_by_hand(chips, module, least):
     assert got == pytest.approx(100 * least * 2 / 0.5 / 1000)
     run.train["step_module"] = "jit_other"
     assert spec.reader("train_step_roofline")(run) is None
+
+
+def test_train_step_roofline_reads_list_form_and_rowwise_by_hand():
+    """The reader takes a batch in the list form and a row-wise optimizer:
+    feature 0's ids 0, 3 (one a bag) touch 4 rows, feature 1's 0, 4, 8
+    (two a bag) 6, of 2 values each; a table row moves its values and
+    gradients (16 B each) and one accumulator (8 B), the 46 dense
+    parameters 24 B each: 400 + 1104 B a step."""
+    model = {"family": "dlrm", "dense_dim": 2, "table_sizes": [5, 9],
+             "emb_dim": 2, "num_collisions": 4, "bottom_mlp": [3],
+             "top_mlp": [4]}
+    train = {"name": "adagrad", "tables": "rowwise"}
+    summary = tracing.TraceSummary(
+        window_s=1.0, busy_s=0.5, devices=1, module_s={"jit_step": 0.5},
+        module_calls={"jit_step": 2}, op_s={}, device_ops=[], idle_gaps=[])
+    batch = {"sparse": [np.array([[0], [3]]), np.array([[0, 4], [8, 4]])]}
+    run = harness.Run({"chips": 1}, {"model": model, "train": train}, {},
+                      {"hbm_bytes_per_s": 1000.0}, trace=summary,
+                      train={"traced_steps": [5, 6], "step_module": "jit_step",
+                             "batch_at": lambda s: batch})
+    got = spec.reader("train_step_roofline")(run)
+    assert got == pytest.approx(100 * (400 + 1104) * 2 / 0.5 / 1000)
